@@ -198,6 +198,9 @@ def run(quick: bool = True, out: str = "BENCH_slam.json"):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

@@ -9,9 +9,12 @@ metrics: on this container the "devices" are forced host-platform slices
 of one CPU core, so wall clock does NOT improve with D), and mean queue
 wait — and appends a ``"serve"`` row to ``BENCH_slam.json``.
 
-Device counts need ``--xla_force_host_platform_device_count`` set before
-JAX initializes, so each D runs in its own worker subprocess (the
-tests/test_multidevice.py pattern); the parent aggregates the workers'
+On an accelerator every device count runs in this process over
+``make_data_mesh(D)`` on the real devices (a chip belongs to one process,
+so a child could not get the one the parent holds).  On the CPU the
+"devices" need ``--xla_force_host_platform_device_count`` set before JAX
+initializes, so each D runs in its own worker subprocess (the
+tests/test_multidevice.py pattern) and the parent aggregates the workers'
 JSON lines.
 
 Run:  PYTHONPATH=src python -m benchmarks.run --only serve
@@ -32,12 +35,12 @@ import sys
 _RESULT_TAG = "SERVE_RESULT "
 
 
-def _worker(devices: int, sessions: int, num_frames: int,
-            trace_out: str = "") -> None:
-    """Runs inside a subprocess with D forced host devices: time one
-    serving epoch of S streams through ShardedPool + SlamServer, with a
-    SlamScope sink attached (the measured epoch is telemetry-on — the
-    zero-overhead invariant means the numbers are the production numbers)."""
+def _measure(devices: int, sessions: int, num_frames: int,
+             trace_out: str = "") -> dict:
+    """Time one serving epoch of S streams through ShardedPool + SlamServer
+    on the first D devices, with a SlamScope sink attached (the measured
+    epoch is telemetry-on — the zero-overhead invariant means the numbers
+    are the production numbers)."""
     import jax
 
     from repro.core.keyframes import KeyframePolicy
@@ -89,7 +92,8 @@ def _worker(devices: int, sessions: int, num_frames: int,
                   for f in ("fragments", "pixels", "unstable_gaussians")}
         for i in range(sessions)}
     tele.export_trace(trace_out)
-    print(_RESULT_TAG + json.dumps({
+    return {
+        "platform": jax.devices()[0].platform,
         "devices": devices,
         "sessions": sessions,
         "frame_steps": steps,
@@ -108,7 +112,7 @@ def _worker(devices: int, sessions: int, num_frames: int,
         "work_per_stream": work_per_stream,
         "ate_cm": [round(f.ate * 100, 2) for f in fins],
         "psnr_db": [round(f.mean_psnr, 2) for f in fins],
-    }))
+    }
 
 
 def _spawn(devices: int, sessions: int, num_frames: int,
@@ -139,16 +143,23 @@ def _spawn(devices: int, sessions: int, num_frames: int,
 
 def run(quick: bool = True, out: str = "BENCH_slam.json",
         trace: bool = True):
+    import jax
+
     from benchmarks.common import emit, stamp
 
     device_counts = (1, 2) if quick else (1, 2, 4)
     sessions = 4 if quick else 8
     num_frames = 4 if quick else 8
+    # Real devices: measure in this process, over as many as exist.
+    in_process = jax.default_backend() != "cpu"
+    if in_process:
+        device_counts = [d for d in device_counts if d <= jax.device_count()]
 
     rows = {}
     for d in device_counts:
         trace_out = f"bench_serve_trace_D{d}.json" if trace else ""
-        r = _spawn(d, sessions, num_frames, trace_out=trace_out)
+        measure = _measure if in_process else _spawn
+        r = measure(d, sessions, num_frames, trace_out=trace_out)
         if trace_out:
             r["trace"] = trace_out
         rows[f"D{d}"] = r
@@ -481,12 +492,15 @@ def run_v2(quick: bool = True, out: str = "BENCH_slam.json",
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_slam.json")
     ap.add_argument("--worker", action="store_true",
                     help="(internal) run one device-count measurement in "
-                         "this process; requires XLA_FLAGS set by the "
-                         "parent")
+                         "this process; on the CPU requires XLA_FLAGS set "
+                         "by the parent")
     ap.add_argument("--devices", type=int, default=1)
     ap.add_argument("--sessions", type=int, default=4)
     ap.add_argument("--frames", type=int, default=4)
@@ -508,8 +522,9 @@ if __name__ == "__main__":
                            "smoke jobs)")
     args = ap.parse_args()
     if args.worker:
-        _worker(args.devices, args.sessions, args.frames,
-                trace_out=args.trace_out)
+        print(_RESULT_TAG + json.dumps(_measure(
+            args.devices, args.sessions, args.frames,
+            trace_out=args.trace_out)))
     elif args.v2:
         run_v2(quick=not args.full, out=args.out, trace=not args.no_trace)
     else:

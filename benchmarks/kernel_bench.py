@@ -46,4 +46,7 @@ def run(quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     run(quick=False)

@@ -61,4 +61,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     main()

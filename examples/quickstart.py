@@ -16,6 +16,9 @@ from repro.core.losses import psnr, slam_loss
 from repro.core.raster_api import RasterPlan, registered_backends
 from repro.core.render import render
 from repro.core.sorting import make_tile_grid
+from repro.launch.cache import use_compile_cache
+
+use_compile_cache()
 
 # --- a toy scene: 400 Gaussians on a plane + a blob ------------------------
 key = jax.random.PRNGKey(0)

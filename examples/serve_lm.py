@@ -15,5 +15,8 @@ import sys
 from repro.launch import serve
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     sys.argv = [sys.argv[0]] + (sys.argv[1:] or ["--arch", "zamba2-1.2b", "--gen", "24"])
     serve.main()

@@ -18,6 +18,8 @@ Parameterization (standard 3DGS):
 
 from __future__ import annotations
 
+import math
+
 from typing import NamedTuple
 
 import jax
@@ -105,7 +107,7 @@ def from_points(
     assert n <= capacity, f"{n} points exceed capacity {capacity}"
     g = empty(capacity)
     inv_sig = jnp.log(jnp.clip(colors, 1e-4, 1 - 1e-4) / (1 - jnp.clip(colors, 1e-4, 1 - 1e-4)))
-    logit_op = float(jnp.log(opacity / (1 - opacity)))
+    logit_op = math.log(opacity / (1 - opacity))
     return g._replace(
         mu=g.mu.at[:n].set(points),
         log_scale=g.log_scale.at[:n].set(jnp.log(scale)),

@@ -9,7 +9,8 @@ positional arrays + backend-specific kwargs:
   it vmaps/scans/dons like any other bundle; a leading view axis on every
   leaf means "batched multi-view".
 * :class:`RasterPlan` — everything *static* about how to execute: tile grid,
-  chunk size, fragment capacity, backend name, interpret mode — plus the one
+  chunk size, fragment capacity, backend name, Pallas interpret mode
+  (derived from the platform unless given) — plus the one
   dynamic execution input, an optional carried
   :class:`~repro.core.schedule.TileSchedule`.  Registered as a pytree whose
   only child is the schedule, so a plan can ride a ``lax.scan`` carry while
@@ -35,6 +36,7 @@ import jax.numpy as jnp
 from repro.core.projection import ProjectedGaussians
 from repro.core.schedule import TileSchedule
 from repro.core.sorting import FragmentLists, TileGrid
+from repro.kernels import resolve_interpret
 
 
 class RasterInputs(NamedTuple):
@@ -80,9 +82,13 @@ class RasterPlan:
     backend: str = "ref"        # registry name, see register_backend()
     chunk: int = 16             # kernel chunk size (C)
     capacity: int = 128         # fragments per tile (K)
-    interpret: bool = True      # Pallas interpret mode (CPU container)
+    interpret: Optional[bool] = None  # Pallas interpret mode; None = CPU only
     sched_bucket: int = 1       # WSU trip-count bucketing (schedule backend)
     sched: Optional[TileSchedule] = None  # carried schedule (dynamic)
+
+    def __post_init__(self):
+        object.__setattr__(self, "interpret",
+                           resolve_interpret(self.interpret))
 
     def tree_flatten(self):
         return (self.sched,), self.static_leaves

@@ -44,7 +44,7 @@ class RenderConfig(NamedTuple):
     capacity: int = 128          # fragments per tile (K)
     chunk: int = 16              # kernel chunk size (C)
     backend: str = "ref"         # any registered raster backend
-    interpret: bool = True       # Pallas interpret mode (CPU container)
+    interpret: Optional[bool] = None  # Pallas interpret mode; None = CPU only
     background: tuple = (0.0, 0.0, 0.0)
     sched_bucket: int = 1        # WSU trip-count bucketing (schedule backend)
 

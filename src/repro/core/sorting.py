@@ -4,7 +4,8 @@ GPU 3DGS builds dynamic per-tile fragment lists with atomic counters and a
 global radix sort. Neither exists on TPU/XLA, so we build **static-capacity**
 fragment lists: every tile owns ``K`` slots of Gaussian indices in ascending
 depth order (``-1`` padding). Construction is a single global depth argsort +
-a cumulative-position scatter — no per-tile sorting, no atomics.
+a per-slot binary search over cumulative tile positions — no per-tile
+sorting, no atomics.
 
 Capacity overflow (more than K Gaussians on a tile) drops the *deepest*
 fragments, which is the correct priority (near-opaque front fragments occlude
@@ -98,13 +99,14 @@ def build_fragment_lists(
     count = jnp.minimum(pos[:, -1], capacity)
     overflow = jnp.sum(jnp.maximum(pos[:, -1] - capacity, 0))
 
-    keep = m & (pos <= capacity)
-    rows = jnp.broadcast_to(jnp.arange(grid.num_tiles, dtype=jnp.int32)[:, None], m.shape)
-    cols = jnp.where(keep, pos - 1, capacity)  # dropped -> out-of-range col
-    out = jnp.full((grid.num_tiles, capacity), -1, jnp.int32)
-    out = out.at[rows.reshape(-1), cols.reshape(-1)].set(
-        jnp.broadcast_to(order[None, :], m.shape).reshape(-1), mode="drop"
-    )
+    # Slot j of tile t holds the tile's (j+1)-th member in depth order: the
+    # first sorted position where its running count reaches j+1.  One binary
+    # search per slot (T*K*log N reads) rather than a (T, N) scatter, which
+    # the TPU compiler expands into code and compile time linear in N.
+    slot = jnp.arange(1, capacity + 1, dtype=jnp.int32)
+    first = jax.vmap(lambda p: jnp.searchsorted(p, slot, side="left"))(pos)
+    out = jnp.where(slot[None, :] <= count[:, None],
+                    order[jnp.minimum(first, n - 1)], -1).astype(jnp.int32)
     return FragmentLists(idx=out, count=count, overflow=overflow, total=total)
 
 

@@ -1,3 +1,14 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the rasterizer (forward, backward) and the GMU merge."""
+
+from __future__ import annotations
+
+import jax
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Pallas interpret mode: an explicit flag wins; ``None`` derives it from
+    the platform — the interpreter only where the kernels would run on the
+    CPU (which has no Mosaic backend), compiled kernels everywhere else."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)

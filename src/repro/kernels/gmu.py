@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def segment_merge_scatter(vals: jnp.ndarray, ids: jnp.ndarray, num_segments: int) -> jnp.ndarray:
     """Baseline: flat unsorted scatter-add (GPU atomic-add analogue).
@@ -71,7 +73,8 @@ def _block_cumsum_kernel(vals_ref, out_ref, carry_ref, *, block: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def block_cumsum(vals: jnp.ndarray, block: int = 256, interpret: bool = True) -> jnp.ndarray:
+def block_cumsum(vals: jnp.ndarray, block: int = 256,
+                 interpret: bool | None = None) -> jnp.ndarray:
     """Pallas carried blocked prefix sum along axis 0 of (M, G).
 
     The grid runs sequentially on a TPU core; the carry lives in VMEM scratch
@@ -87,7 +90,7 @@ def block_cumsum(vals: jnp.ndarray, block: int = 256, interpret: bool = True) ->
         out_specs=pl.BlockSpec((1, block, g), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((grid, block, g), vals.dtype),
         scratch_shapes=[pltpu.VMEM((1, g), vals.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(vals.reshape(grid, block, g)).reshape(m, g)
 
 
@@ -97,7 +100,7 @@ def segment_merge(
     ids: jnp.ndarray,
     num_segments: int,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """GMU level 2: sorted run-reduction merge.
 

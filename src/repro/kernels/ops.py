@@ -459,7 +459,7 @@ def rasterize(*args, **kwargs):
     grid = kwargs.pop("grid")
     backend = kwargs.pop("backend", "ref")
     chunk = kwargs.pop("chunk", 16)
-    interpret = kwargs.pop("interpret", True)
+    interpret = kwargs.pop("interpret", None)
     sched = kwargs.pop("sched", None)
     if kwargs:
         raise TypeError(f"unknown rasterize() kwargs: {sorted(kwargs)}")

@@ -18,6 +18,7 @@ reference for the hand-derived Pallas backward. Memory is O(tiles * 256 * K)
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.sorting import TILE, TileGrid
@@ -77,8 +78,11 @@ def blend(attrs: jnp.ndarray, alpha: jnp.ndarray):
     w = texc * alpha * include  # (T,256,K)
 
     rgb = attrs[:, 5:8]         # (T,3,K)
-    color = jnp.einsum("tpk,tck->tpc", w, rgb)
-    depth = jnp.einsum("tpk,tk->tp", w, attrs[:, 9])
+    # The oracle's sums stay f32 on every backend (a TPU's default f32
+    # einsum is one bf16 pass).
+    hi = jax.lax.Precision.HIGHEST
+    color = jnp.einsum("tpk,tck->tpc", w, rgb, precision=hi)
+    depth = jnp.einsum("tpk,tk->tp", w, attrs[:, 9], precision=hi)
     final_t = jnp.prod(1.0 - alpha * include, axis=-1)
     return color, depth, final_t
 

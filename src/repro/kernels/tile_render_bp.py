@@ -18,7 +18,7 @@ blend chain reconstruct transmittance and the suffix sums.
 where s_k = gC . c_k + gD d_k is the fragment's blend-weight cotangent.
 
 The per-pixel fragment gradients are reduced over the tile's 256 pixels
-*inside* the kernel (VMEM accumulators) — this is **GMU level 1**: the
+*inside* the kernel (a (10, K) register carry) — this is **GMU level 1**: the
 (tile, gaussian) gradient leaves the kernel already merged, shrinking the
 downstream scatter by 256x. Level 2 (tile -> Gaussian) happens outside in
 ``gmu.segment_merge``.
@@ -45,137 +45,152 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sorting import TileGrid
+from repro.kernels import resolve_interpret
 from repro.kernels.ref import ALPHA_MAX, NUM_ATTRS, PIX, TERM_EPS
-from repro.kernels.tile_render import DEFAULT_CHUNK, _pixel_coords
+from repro.kernels.tile_render import (
+    COLS,
+    DEFAULT_CHUNK,
+    OUT_ROWS,
+    _chunk_columns,
+    _load_columns,
+    _pixel_coords,
+)
 
 NUM_GRADS = 10  # mu_x, mu_y, conic_a, conic_b, conic_c, r, g, b, opacity, depth
 
 
-def _pass_a_chunk(attrs_ref, alpha, start, chunk, g_r, g_g, g_b, g_d, carry):
+def _pass_a_chunk(blk, alpha, chunk, g_r, g_g, g_b, g_d, carry):
     """Multiply-only forward replay over one chunk: accumulates total_ws and
     advances transmittance.  Shared op-for-op by both backward kernels."""
     trans, total_ws = carry
     for i in range(chunk):
-        k = start + i
         a = alpha[i:i + 1, :]
+        f = blk[i:i + 1, :]
         include = (trans > TERM_EPS).astype(jnp.float32)
         am = a * include
         w = trans * am
-        s = (g_r * attrs_ref[0, 5, k] + g_g * attrs_ref[0, 6, k]
-             + g_b * attrs_ref[0, 7, k] + g_d * attrs_ref[0, 9, k])
+        s = (g_r * f[:, 5:6] + g_g * f[:, 6:7]
+             + g_b * f[:, 7:8] + g_d * f[:, 9:10])
         total_ws += w * s
         trans = trans * (1.0 - am)
     return trans, total_ws
 
 
-def _pass_b_chunk(attrs_ref, grads_ref, row, alpha, start, chunk, px, py,
-                  g_r, g_g, g_b, g_d, total_ws, ft_gt, carry):
+def _pass_b_chunk(blk, alpha, start, chunk, px, py, g_r, g_g, g_b, g_d,
+                  total_ws, ft_gt, frag_lane, carry):
     """Fragment gradients over one chunk, merged over the 256 pixels (GMU
-    level 1) into ``grads_ref[row, :, k]``.  Shared by both kernels."""
-    trans, prefix = carry
+    level 1) into column ``k`` of the (10, K) gradient carry.  Shared by
+    both kernels."""
+    trans, prefix, grads = carry
     for i in range(chunk):
-        k = start + i
         a = alpha[i:i + 1, :]
+        f = blk[i:i + 1, :]
         include = (trans > TERM_EPS).astype(jnp.float32)
         am = a * include
         w = trans * am
-        col_r = attrs_ref[0, 5, k]
-        col_g = attrs_ref[0, 6, k]
-        col_b = attrs_ref[0, 7, k]
-        dep = attrs_ref[0, 9, k]
-        s = g_r * col_r + g_g * col_g + g_b * col_b + g_d * dep
+        s = g_r * f[:, 5:6] + g_g * f[:, 6:7] + g_b * f[:, 7:8] + g_d * f[:, 9:10]
         prefix += w * s
         suffix = total_ws - prefix          # sum_{j>k} w_j s_j
         dam = trans * s - (suffix + ft_gt) / (1.0 - am)
         da = dam * include                  # (1,256)
 
         # chain to conic / position / opacity (clip + cutoff masks).
-        o = attrs_ref[0, 8, k]
+        o = f[:, 8:9]
         clip = (a < ALPHA_MAX).astype(jnp.float32)
         dq = da * (-0.5 * a) * clip         # d alpha/d q = -0.5 o G
-        dx = px - attrs_ref[0, 0, k]
-        dy = py - attrs_ref[0, 1, k]
-        ca = attrs_ref[0, 2, k]
-        cb = attrs_ref[0, 3, k]
-        cc = attrs_ref[0, 4, k]
+        dx = px - f[:, 0:1]
+        dy = py - f[:, 1:2]
+        ca, cb, cc = f[:, 2:3], f[:, 3:4], f[:, 4:5]
 
-        # GMU level 1: reduce each fragment gradient over 256 pixels.
-        grads_ref[row, 0, k] = jnp.sum(dq * (-2.0) * (ca * dx + cb * dy))
-        grads_ref[row, 1, k] = jnp.sum(dq * (-2.0) * (cb * dx + cc * dy))
-        grads_ref[row, 2, k] = jnp.sum(dq * dx * dx)
-        grads_ref[row, 3, k] = jnp.sum(dq * 2.0 * dx * dy)
-        grads_ref[row, 4, k] = jnp.sum(dq * dy * dy)
-        grads_ref[row, 5, k] = jnp.sum(w * g_r)
-        grads_ref[row, 6, k] = jnp.sum(w * g_g)
-        grads_ref[row, 7, k] = jnp.sum(w * g_b)
-        grads_ref[row, 8, k] = jnp.sum(da * (a / jnp.maximum(o, 1e-12)) * clip)
-        grads_ref[row, 9, k] = jnp.sum(w * g_d)
+        # GMU level 1: reduce each fragment gradient over 256 pixels, then
+        # drop the (10, 1) column into lane k of the carry (a select, so
+        # every other column passes through bit-untouched).
+        rows = jnp.concatenate([
+            dq * (-2.0) * (ca * dx + cb * dy),
+            dq * (-2.0) * (cb * dx + cc * dy),
+            dq * dx * dx,
+            dq * 2.0 * dx * dy,
+            dq * dy * dy,
+            w * g_r,
+            w * g_g,
+            w * g_b,
+            da * (a / jnp.maximum(o, 1e-12)) * clip,
+            w * g_d,
+        ], axis=0)                          # (10,256)
+        col = jnp.sum(rows, axis=1, keepdims=True)
+        grads = jnp.where(frag_lane == start + i, col, grads)
 
         trans = trans * (1.0 - am)
-    return trans, prefix
+    return trans, prefix, grads
 
 
-def _bwd_tile_loops(attrs_ref, stash_ref, grads_ref, row, tile_id, trips,
-                    g_r, g_g, g_b, g_d, g_t, grid_w, chunk):
+def _bwd_tile_loops(attrs_ref, cols_ref, stash_ref, g_ref, grads_ref, row,
+                    tile_id, trips, grid_w, chunk):
     """Both backward passes for one tile, chunk loops bounded by ``trips``
-    (subtile streaming).  Shared op-for-op by the raster-order and
-    WSU-scheduled kernels so gradients stay bit-identical between them."""
+    (subtile streaming), then the tile's (10, K) gradient block.  Shared
+    op-for-op by the raster-order and WSU-scheduled kernels so gradients
+    stay bit-identical between them."""
+    _load_columns(attrs_ref, cols_ref)
     px, py = _pixel_coords(tile_id, grid_w)
-    carry0 = (jnp.ones((1, PIX), jnp.float32), jnp.zeros((1, PIX), jnp.float32))
+    g = g_ref[row]                          # (5,256): r, g, b, depth, final T
+    g_r, g_g, g_b, g_d, g_t = (g[i:i + 1, :] for i in range(OUT_ROWS))
+    ones = jnp.ones((1, PIX), jnp.float32)
+    zeros = jnp.zeros((1, PIX), jnp.float32)
+
+    def chunk_inputs(start):
+        blk = _chunk_columns(cols_ref, start, chunk)
+        alpha = stash_ref[row, pl.ds(pl.multiple_of(start, chunk), chunk), :]
+        return blk, alpha                   # (C,COLS), (C,256) R&B reuse
 
     # ---- pass A: total_ws and final transmittance (multiply-only replay) --
     def trip_a(c, carry):
         start = c * chunk
-        trans = carry[0]
 
         def do_chunk(carry=carry):
-            alpha = stash_ref[row, pl.ds(start, chunk), :]  # (C,256) R&B reuse
-            return _pass_a_chunk(attrs_ref, alpha, start, chunk,
-                                 g_r, g_g, g_b, g_d, carry)
+            blk, alpha = chunk_inputs(start)
+            return _pass_a_chunk(blk, alpha, chunk, g_r, g_g, g_b, g_d, carry)
 
-        return jax.lax.cond(jnp.max(trans) > TERM_EPS, do_chunk,
+        return jax.lax.cond(jnp.max(carry[0]) > TERM_EPS, do_chunk,
                             lambda carry=carry: carry)
 
-    final_t, total_ws = jax.lax.fori_loop(0, trips, trip_a, carry0)
+    final_t, total_ws = jax.lax.fori_loop(0, trips, trip_a, (ones, zeros))
     ft_gt = final_t * g_t  # (1,256)
 
     # ---- pass B: fragment gradients, merged over pixels (GMU level 1) -----
+    capacity = grads_ref.shape[-1]
+    frag_lane = jax.lax.broadcasted_iota(jnp.int32, (NUM_GRADS, capacity), 1)
+
     def trip_b(c, carry):
         start = c * chunk
-        trans = carry[0]
 
         def do_chunk(carry=carry):
-            alpha = stash_ref[row, pl.ds(start, chunk), :]
-            return _pass_b_chunk(attrs_ref, grads_ref, row, alpha, start,
-                                 chunk, px, py, g_r, g_g, g_b, g_d, total_ws,
-                                 ft_gt, carry)
+            blk, alpha = chunk_inputs(start)
+            return _pass_b_chunk(blk, alpha, start, chunk, px, py, g_r, g_g,
+                                 g_b, g_d, total_ws, ft_gt, frag_lane, carry)
 
-        return jax.lax.cond(jnp.max(trans) > TERM_EPS, do_chunk,
+        return jax.lax.cond(jnp.max(carry[0]) > TERM_EPS, do_chunk,
                             lambda carry=carry: carry)
 
-    jax.lax.fori_loop(0, trips, trip_b, carry0)
+    grads0 = jnp.zeros((NUM_GRADS, capacity), jnp.float32)
+    _, _, grads = jax.lax.fori_loop(0, trips, trip_b, (ones, zeros, grads0))
+    grads_ref[row] = grads
 
 
-def _bwd_kernel(
-    attrs_ref, count_ref, stash_ref, g_color_ref, g_depth_ref, g_finalt_ref,
-    grads_ref,
-    *, grid_w: int, capacity: int, chunk: int, tiles: int,
-):
+def _cotangent_rows(g_color, g_depth, g_finalt):
+    """Pixel cotangents as one (T, 5, 256) operand, rows as in the forward's
+    output block."""
+    return jnp.concatenate([g_color, g_depth[:, None], g_finalt[:, None]],
+                           axis=1)
+
+
+def _bwd_kernel(count_ref, attrs_ref, stash_ref, g_ref, grads_ref, cols_ref,
+                *, grid_w: int, chunk: int, tiles: int):
     # Stacked multi-view grids run B*T programs; pixel coords use the
     # in-view tile id (identity when unbatched).
-    tile_id = pl.program_id(0) % tiles
-    count = count_ref[0]
-    trips = (count + chunk - 1) // chunk
-
-    g_r = g_color_ref[0, 0, :][None, :]   # (1,256)
-    g_g = g_color_ref[0, 1, :][None, :]
-    g_b = g_color_ref[0, 2, :][None, :]
-    g_d = g_depth_ref[0, :][None, :]
-    g_t = g_finalt_ref[0, :][None, :]
-
-    grads_ref[...] = jnp.zeros((1, NUM_GRADS, capacity), jnp.float32)
-    _bwd_tile_loops(attrs_ref, stash_ref, grads_ref, 0, tile_id, trips,
-                    g_r, g_g, g_b, g_d, g_t, grid_w, chunk)
+    t = pl.program_id(0)
+    trips = (count_ref[t] + chunk - 1) // chunk
+    _bwd_tile_loops(attrs_ref, cols_ref, stash_ref, g_ref, grads_ref, 0,
+                    t % tiles, trips, grid_w, chunk)
 
 
 @functools.partial(
@@ -189,7 +204,7 @@ def tile_render_bwd(
     g_finalt: jnp.ndarray,  # (T, 256)
     grid: TileGrid,
     chunk: int = DEFAULT_CHUNK,
-    interpret: bool = True,
+    interpret: bool | None = None,
     tiles_per_view: int | None = None,
 ) -> jnp.ndarray:
     """Returns per-(tile, fragment) merged gradients (T, 10, K).
@@ -201,25 +216,26 @@ def tile_render_bwd(
     tiles = tiles_per_view or num_tiles
     assert num_tiles % tiles == 0, (num_tiles, tiles)
 
-    kernel = functools.partial(
-        _bwd_kernel, grid_w=grid.grid_w, capacity=capacity, chunk=chunk,
-        tiles=tiles,
+    kernel = functools.partial(_bwd_kernel, grid_w=grid.grid_w, chunk=chunk,
+                               tiles=tiles)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(num_tiles,),
+        in_specs=[
+            pl.BlockSpec((1, NUM_ATTRS, capacity), lambda t, cnt: (t, 0, 0)),
+            pl.BlockSpec((1, capacity, PIX), lambda t, cnt: (t, 0, 0)),
+            pl.BlockSpec((1, OUT_ROWS, PIX), lambda t, cnt: (t, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, NUM_GRADS, capacity), lambda t, cnt: (t, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((capacity, COLS), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, NUM_ATTRS, capacity), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1,), lambda t: (t,)),
-            pl.BlockSpec((1, capacity, PIX), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, 3, PIX), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, PIX), lambda t: (t, 0)),
-            pl.BlockSpec((1, PIX), lambda t: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, NUM_GRADS, capacity), lambda t: (t, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_tiles, NUM_GRADS, capacity), jnp.float32),
-        interpret=interpret,
-    )(attrs, count, stash, g_color, g_depth, g_finalt)
+        interpret=resolve_interpret(interpret),
+    )(count.astype(jnp.int32), attrs, stash,
+      _cotangent_rows(g_color, g_depth, g_finalt))
 
 
 # ---------------------------------------------------------------------------
@@ -228,26 +244,16 @@ def tile_render_bwd(
 
 
 def _sched_bwd_kernel(perm_ref, trips_ref, attrs_a_ref, attrs_b_ref, stash_ref,
-                      g_color_ref, g_depth_ref, g_finalt_ref, grads_ref,
-                      *, grid_w: int, capacity: int, chunk: int, tiles: int):
+                      g_ref, grads_ref, cols_ref,
+                      *, grid_w: int, chunk: int, tiles: int):
     pair = pl.program_id(0)
-    grads_ref[...] = jnp.zeros((2, NUM_GRADS, capacity), jnp.float32)
-
     for j, attrs_ref in enumerate((attrs_a_ref, attrs_b_ref)):
         slot = 2 * pair + j
         # Stacked schedules hold global rows (view*T + tile); pixel coords
-        # use the in-view tile id (identity when unbatched).
-        tile_id = perm_ref[slot] % tiles
-        trips = trips_ref[slot]
-
-        g_r = g_color_ref[j, 0, :][None, :]   # (1,256), slot-ordered blocks
-        g_g = g_color_ref[j, 1, :][None, :]
-        g_b = g_color_ref[j, 2, :][None, :]
-        g_d = g_depth_ref[j, :][None, :]
-        g_t = g_finalt_ref[j, :][None, :]
-
-        _bwd_tile_loops(attrs_ref, stash_ref, grads_ref, j, tile_id, trips,
-                        g_r, g_g, g_b, g_d, g_t, grid_w, chunk)
+        # use the in-view tile id (identity when unbatched).  Stash and
+        # cotangent blocks are slot-ordered: row j belongs to this slot.
+        _bwd_tile_loops(attrs_ref, cols_ref, stash_ref, g_ref, grads_ref, j,
+                        perm_ref[slot] % tiles, trips_ref[slot], grid_w, chunk)
 
 
 @functools.partial(
@@ -262,7 +268,7 @@ def tile_render_bwd_sched(
     g_finalt: jnp.ndarray,  # (S, 256)
     grid: TileGrid,
     chunk: int = DEFAULT_CHUNK,
-    interpret: bool = True,
+    interpret: bool | None = None,
     tiles_per_view: int | None = None,
 ) -> jnp.ndarray:
     """Scheduled Rendering BP.  The stash and the pixel cotangents arrive in
@@ -279,10 +285,8 @@ def tile_render_bwd_sched(
     assert num_tiles % tiles == 0, (num_tiles, tiles)
     num_pairs = slots // 2
 
-    kernel = functools.partial(
-        _sched_bwd_kernel, grid_w=grid.grid_w, capacity=capacity, chunk=chunk,
-        tiles=tiles,
-    )
+    kernel = functools.partial(_sched_bwd_kernel, grid_w=grid.grid_w,
+                               chunk=chunk, tiles=tiles)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(num_pairs,),
@@ -292,16 +296,16 @@ def tile_render_bwd_sched(
             pl.BlockSpec((1, NUM_ATTRS, capacity),
                          lambda p, perm, trips: (perm[2 * p + 1], 0, 0)),
             pl.BlockSpec((2, capacity, PIX), lambda p, perm, trips: (p, 0, 0)),
-            pl.BlockSpec((2, 3, PIX), lambda p, perm, trips: (p, 0, 0)),
-            pl.BlockSpec((2, PIX), lambda p, perm, trips: (p, 0)),
-            pl.BlockSpec((2, PIX), lambda p, perm, trips: (p, 0)),
+            pl.BlockSpec((2, OUT_ROWS, PIX), lambda p, perm, trips: (p, 0, 0)),
         ],
         out_specs=pl.BlockSpec((2, NUM_GRADS, capacity),
                                lambda p, perm, trips: (p, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((capacity, COLS), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, NUM_GRADS, capacity), jnp.float32),
-        interpret=interpret,
-    )(perm, trips, attrs, attrs, stash, g_color, g_depth, g_finalt)
+        interpret=resolve_interpret(interpret),
+    )(perm, trips, attrs, attrs, stash,
+      _cotangent_rows(g_color, g_depth, g_finalt))

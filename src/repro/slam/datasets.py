@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core import gaussians as G
 from repro.core.camera import Camera, Intrinsics, look_at
+from repro.core.lie import f32_jit
 from repro.core.raster_api import RasterPlan
 from repro.core.render import render
 from repro.core.sorting import make_tile_grid
@@ -330,7 +331,7 @@ def make_dataset(
     grid = make_tile_grid(height, width)
     plan = RasterPlan(grid=grid, backend="ref", capacity=frag_capacity)
 
-    @jax.jit
+    @f32_jit
     def render_frame(w2c):
         out = render(gt, Camera(intr, w2c), plan)
         depth = jnp.where(out.alpha > 0.5, out.depth / jnp.maximum(out.alpha, 1e-6), 0.0)

@@ -47,6 +47,7 @@ import numpy as np
 from repro.core import gaussians as G
 from repro.core import lie, pruning
 from repro.core.camera import Camera, Intrinsics
+from repro.core.lie import f32_jit
 from repro.core.losses import slam_loss
 from repro.core.raster_api import RasterPlan, static_fingerprint
 from repro.core.render import render
@@ -72,17 +73,6 @@ from repro.train.optimizer import (
     apply_updates,
     apply_updates_masked,
 )
-
-
-def _donate_kwargs(*argnames) -> dict:
-    """``jax.jit`` donation kwargs for the named arguments — empty on
-    XLA:CPU, which doesn't implement buffer donation (donating there only
-    produces warnings).  Every jit that wants to donate carried state
-    (scan bundles, session steps, the sharded serving pool) must build its
-    kwargs through this helper instead of hand-writing the backend guard."""
-    if jax.default_backend() == "cpu":
-        return {}
-    return {"donate_argnames": argnames}
 
 
 def silence(g: G.GaussianField, masked: jnp.ndarray) -> G.GaussianField:
@@ -218,20 +208,21 @@ class _Stage:
             raise ValueError("sparse_opt=True requires cfg.prune (the "
                              "stability bit rides PruneState)")
 
-        donate = _donate_kwargs("g", "pstate", "work")
-        self.build = jax.jit(self._build_core)
-        self.build_sparse = jax.jit(self._sparse_build_core)
-        self.slot_programs = jax.jit(self._slot_programs_core)
-        self.track_iter = jax.jit(self._track_iter_core)
-        self.map_iter = jax.jit(self._map_iter_core)
-        self.stable_bg = jax.jit(self._stable_bg_core)
-        self.render_eval = jax.jit(self._render_eval_core)
-        self.track_scan_noprune = jax.jit(self._track_scan_noprune)
+        self.build = f32_jit(self._build_core)
+        self.build_sparse = f32_jit(self._sparse_build_core)
+        self.slot_programs = f32_jit(self._slot_programs_core)
+        self.track_iter = f32_jit(self._track_iter_core)
+        self.map_iter = f32_jit(self._map_iter_core)
+        self.stable_bg = f32_jit(self._stable_bg_core)
+        self.render_eval = f32_jit(self._render_eval_core)
+        self.track_scan_noprune = f32_jit(self._track_scan_noprune)
         if cfg.prune is not None:
-            self.track_scan_prune = jax.jit(self._track_scan_prune, **donate)
-        donate_map = _donate_kwargs("g", "opt_state", "work")
-        self.map_scan = jax.jit(self._map_scan, **donate_map)
-        self.map_scan_masked = jax.jit(self._map_scan_masked, **donate_map)
+            self.track_scan_prune = f32_jit(
+                self._track_scan_prune, donate_argnames=("g", "pstate", "work"))
+        donate_map = ("g", "opt_state", "work")
+        self.map_scan = f32_jit(self._map_scan, donate_argnames=donate_map)
+        self.map_scan_masked = f32_jit(self._map_scan_masked,
+                                       donate_argnames=donate_map)
 
     # ---- cores (pure, shared by fused scans and per-iteration jits) -----
 
@@ -883,7 +874,7 @@ class StepEngine:
             key = (self.intr, cfg.lr_pose, cfg.iters_track)
             geo_scan, geo_vg = get_geo_scan(self.intr, cfg)
             if key not in _GEO_JIT_CACHE:
-                _GEO_JIT_CACHE[key] = jax.jit(geo_scan)
+                _GEO_JIT_CACHE[key] = f32_jit(geo_scan)
             self._geo, self._geo_vg = _GEO_JIT_CACHE[key], geo_vg
 
         base = jnp.asarray(base_w2c)

@@ -88,4 +88,4 @@ def make_geometric_tracker(intr: Intrinsics, lambda_pho: float = 0.7):
         e_geo = jnp.sum(jnp.abs(samp_d - z) * d_ok) / jnp.maximum(d_ok.sum(), 1.0)
         return lambda_pho * e_pho + (1 - lambda_pho) * e_geo
 
-    return jax.jit(jax.value_and_grad(loss_fn))
+    return lie.f32_jit(jax.value_and_grad(loss_fn))

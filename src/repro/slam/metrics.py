@@ -55,11 +55,9 @@ class DeviceWork(NamedTuple):
 
 
 def device_work_zero() -> DeviceWork:
-    z = jnp.zeros((), jnp.int32)
-    return DeviceWork(fragments=z, pixels=z, gaussians_iters=z, iterations=z,
-                      unstable_gaussians=z, sched_programs=z,
-                      skipped_fragments=z, densify_dropped=z,
-                      frag_build_rows=z)
+    # One buffer per field: the fused bundles donate ``work``, and a buffer
+    # donated twice in one call is refused.
+    return DeviceWork(*(jnp.zeros((), jnp.int32) for _ in DeviceWork._fields))
 
 
 def device_work_add(w: DeviceWork, fragments, pixels, alive,

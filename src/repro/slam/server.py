@@ -54,10 +54,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.core.lie import f32_jit
 from repro.distributed.sharding import to_shardings
 from repro.launch.mesh import axis_size, make_data_mesh
 from repro.obs import Stopwatch, Telemetry, now_s, telemetry_or_off
-from repro.slam.engine import EngineStats, _donate_kwargs
+from repro.slam.engine import EngineStats
 from repro.slam.session import (
     Observation,
     SLAMResult,
@@ -124,8 +125,8 @@ class ShardedPool:
     The stacked :class:`SlamSession` pytree is placed with
     ``NamedSharding(mesh, P("data"))`` on every leaf's leading S axis, so
     each device owns S/D complete session rows.  :meth:`step` runs the
-    shared ``make_many_step`` trace under those shardings (session state
-    buffers donated where the backend supports it) — one executable, one
+    shared ``make_many_step`` trace on each device's own rows
+    (``shard_map``; session state buffers donated) — one executable, one
     dispatch per frame-step, rows bitwise-equal to single-device
     ``step_many``.  :meth:`swap` is the admission tier's device op: replace
     one row across the shards via a slot-traced cached executable.
@@ -205,11 +206,19 @@ class ShardedPool:
         obs = self.stage(frames)
         key = ("serve-step",) + self._cache_key()
         if key not in _SERVE_STEP_CACHE:
-            _SERVE_STEP_CACHE[key] = jax.jit(
-                make_many_step(self.meta, self.size),
+            # Each device steps its own S/D rows: the Pallas kernels inside
+            # cannot be partitioned by the compiler, so the row trace runs
+            # per shard.
+            rows = P("data")
+            local = jax.shard_map(
+                make_many_step(self.meta, self.size // self.num_devices),
+                mesh=self.mesh, in_specs=(rows, rows),
+                out_specs=(rows, rows), check_vma=False)
+            _SERVE_STEP_CACHE[key] = f32_jit(
+                local,
                 in_shardings=(self.sharding, self.sharding),
                 out_shardings=(self.sharding, self.sharding),
-                **_donate_kwargs("stacked"))
+                donate_argnums=0)
         self.stats.dispatches += 1
         self._stacked, res = _SERVE_STEP_CACHE[key](self._stacked, obs)
         return res
@@ -238,7 +247,7 @@ class ShardedPool:
                 swap,
                 in_shardings=(self.sharding, self.row_sharding, None),
                 out_shardings=(self.sharding, self.row_sharding),
-                **_donate_kwargs("stacked"))
+                donate_argnames="stacked")
         self.admin_dispatches += 1
         self._stacked, old = _SERVE_SWAP_CACHE[key](
             self._stacked, new_session, jnp.asarray(slot, jnp.int32))

@@ -53,6 +53,7 @@ from repro.core.downsample import (
     side_factor,
 )
 from repro.core.keyframes import KeyframePolicy
+from repro.core.lie import f32_jit
 from repro.core.losses import psnr as psnr_dev
 from repro.core.raster_api import static_fingerprint
 from repro.core.render import render
@@ -68,7 +69,6 @@ from repro.slam.datasets import SLAMDataset
 from repro.slam.engine import (
     EngineStats,
     StepEngine,
-    _donate_kwargs,
     get_geo_scan,
     get_stage,
     silence,
@@ -703,10 +703,10 @@ def _step_fn(meta: SessionMeta, factor: int, batch: Optional[int]):
 
             def solo(sess, obs: Observation):
                 return row_step(sess, obs.rgb, obs.depth)
-            _STEP_CACHE[key] = jax.jit(solo, **_donate_kwargs("sess"))
+            _STEP_CACHE[key] = f32_jit(solo, donate_argnames="sess")
         else:
-            _STEP_CACHE[key] = jax.jit(make_many_step(meta, batch, factor),
-                                       **_donate_kwargs("stacked"))
+            _STEP_CACHE[key] = f32_jit(make_many_step(meta, batch, factor),
+                                       donate_argnames="stacked")
     return _STEP_CACHE[key]
 
 
@@ -791,7 +791,9 @@ def session_init(dataset: SLAMDataset, cfg: SLAMConfig, *,
         frame_idx=jnp.asarray(1, jnp.int32),
         kf_rgb=kf_rgb, kf_depth=kf_depth, kf_w2c=kf_w2c,
         kf_count=jnp.asarray(1, jnp.int32), kf_total=jnp.asarray(1, jnp.int32),
-        last_kf_idx=jnp.asarray(0, jnp.int32), last_kf_rgb=rgb0,
+        # Every leaf owns its buffer: the step donates the whole session,
+        # and one buffer donated twice is refused.
+        last_kf_idx=jnp.asarray(0, jnp.int32), last_kf_rgb=jnp.array(rgb0),
         prev_rgb=rgb0, prev_depth=depth0,
         kf_psnr=jnp.full((num_f,), jnp.nan, jnp.float32).at[0].set(psnr0),
         alive_log=jnp.zeros((num_f,), jnp.int32).at[0].set(alive0),
@@ -825,7 +827,7 @@ def _boot_fn(meta: SessionMeta):
                        if st_1.scheduled else None)
             return g, opt, work_m, psnr0, g.num_alive(), frags_l, sched_l
 
-        _BOOT_CACHE[key] = jax.jit(boot)
+        _BOOT_CACHE[key] = f32_jit(boot)
     return _BOOT_CACHE[key]
 
 
@@ -992,8 +994,10 @@ def run_sequence(dataset: SLAMDataset, cfg: SLAMConfig,
                       f"psnr={float(psnr_buf[int(total) - 1]):.2f}")
 
     result = session_finalize(
-        sess, gt_w2c=[f.w2c_gt for f in dataset.frames],
-        wall_time_s=run_sw.elapsed(), stats=stats)
+        sess, gt_w2c=[f.w2c_gt for f in dataset.frames], stats=stats)
+    # Read after finalize's fetch: on an async device the loop above only
+    # enqueued the steps.
+    result.wall_time_s = run_sw.elapsed()
     tele.result(stream, result)
     return result
 
@@ -1042,7 +1046,7 @@ def _densify_jit(meta: SessionMeta):
         def fn(g, rgb, depth, rendered, w2c, k):
             return _densify_core(g, rgb, depth, rendered, w2c, intr, cfg, k)
 
-        _AUX_JIT_CACHE[key] = jax.jit(fn)
+        _AUX_JIT_CACHE[key] = f32_jit(fn)
     return _AUX_JIT_CACHE[key]
 
 
