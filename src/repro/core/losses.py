@@ -10,7 +10,10 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.obs.profiling import scoped
 
+
+@scoped("loss")
 def slam_loss(
     rendered_rgb: jnp.ndarray,   # (H, W, 3)
     rendered_depth: jnp.ndarray,  # (H, W) premultiplied by alpha
@@ -31,6 +34,7 @@ def slam_loss(
     return lambda_pho * e_pho + (1.0 - lambda_pho) * e_geo
 
 
+@scoped("loss")
 def psnr(a: jnp.ndarray, b: jnp.ndarray, max_val: float = 1.0) -> jnp.ndarray:
     mse = jnp.mean((a - b) ** 2)
     return 10.0 * jnp.log10(max_val**2 / jnp.maximum(mse, 1e-12))

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.core.camera import Camera
 from repro.core.gaussians import GaussianField
+from repro.obs.profiling import scoped
 
 # Low-pass filter added to 2D covariance (standard 3DGS; guarantees a
 # minimum splat size of ~0.3px so conics stay invertible).
@@ -32,6 +33,7 @@ class ProjectedGaussians(NamedTuple):
     valid: jnp.ndarray   # (N,) bool — alive, in front of camera, on screen
 
 
+@scoped("project")
 def project(g: GaussianField, cam: Camera) -> ProjectedGaussians:
     intr = cam.intrinsics
     W = cam.w2c[:3, :3]

@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.gaussians import GaussianField
+from repro.obs.profiling import scoped
 
 
 class PruneConfig(NamedTuple):
@@ -141,6 +142,7 @@ def importance_scores(param_grads: dict, cfg: PruneConfig) -> jnp.ndarray:
     return g_mu + cfg.lam * g_cov
 
 
+@scoped("prune")
 def accumulate(state: PruneState, param_grads: dict, cfg: PruneConfig,
                alive: jnp.ndarray | None = None) -> PruneState:
     """Per-tracking-iteration score accumulation (jit-safe).
@@ -304,6 +306,7 @@ def interval_update(
     return new_state, g._replace(alive=alive), want > 0
 
 
+@scoped("prune")
 def cond_interval_update(
     state: PruneState,
     g: GaussianField,

@@ -34,6 +34,7 @@ from repro.core.raster_api import RasterInputs, RasterPlan, warn_once
 from repro.core.schedule import TileSchedule
 from repro.core.sorting import FragmentLists, TileGrid, build_fragment_lists
 from repro.kernels import ops
+from repro.obs.profiling import scoped
 
 
 class RenderConfig(NamedTuple):
@@ -107,6 +108,7 @@ def _render_batched(g: GaussianField, cam: Camera, plan: RasterPlan,
                         final_t=final_t, frags=frags, proj=proj)
 
 
+@scoped("raster")
 def render(
     g: GaussianField,
     cam: Camera,

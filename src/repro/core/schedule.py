@@ -36,6 +36,7 @@ from typing import NamedTuple, Optional
 import jax.numpy as jnp
 
 from repro.core.sorting import balanced_pair_permutation
+from repro.obs.profiling import scoped
 
 
 class TileSchedule(NamedTuple):
@@ -65,6 +66,7 @@ def _inverse_slots(perm: jnp.ndarray, num_tiles: int) -> jnp.ndarray:
     return jnp.full((num_tiles,), -1, jnp.int32).at[perm].max(slots)
 
 
+@scoped("wsu_schedule")
 def build_schedule(
     count: jnp.ndarray,
     chunk: int,

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.projection import ProjectedGaussians
+from repro.obs.profiling import scoped
 
 TILE = 16  # pixels per tile side (paper convention)
 
@@ -55,6 +56,7 @@ class FragmentLists(NamedTuple):
     total: jnp.ndarray     # () int32 total tile-Gaussian intersections (pre-drop)
 
 
+@scoped("frag_build")
 def build_fragment_lists(
     proj: ProjectedGaussians, grid: TileGrid, capacity: int,
     keep: jnp.ndarray | None = None,
@@ -110,6 +112,7 @@ def build_fragment_lists(
     return FragmentLists(idx=out, count=count, overflow=overflow, total=total)
 
 
+@scoped("frag_build")
 def count_skipped_fragments(
     proj: ProjectedGaussians, grid: TileGrid, keep: jnp.ndarray
 ) -> jnp.ndarray:
@@ -151,6 +154,7 @@ def stack_fragment_lists(lists: list["FragmentLists"]) -> FragmentLists:
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *lists)
 
 
+@scoped("frag_build")
 def update_fragment_slot(stack: FragmentLists, i, fresh: FragmentLists) -> FragmentLists:
     """Write a freshly built list into window slot ``i`` of a stacked cache
     (the Obs. 6 stride-rebuild inside the mapping scan)."""

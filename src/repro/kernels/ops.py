@@ -55,6 +55,7 @@ from repro.core.sorting import FragmentLists, TileGrid
 from repro.kernels import gmu, ref
 from repro.kernels.tile_render import tile_render_fwd, tile_render_fwd_sched
 from repro.kernels.tile_render_bp import tile_render_bwd, tile_render_bwd_sched
+from repro.obs.profiling import scoped
 
 _FLOAT0 = jax.dtypes.float0
 
@@ -406,6 +407,7 @@ def _schedule_backend(inputs: RasterInputs, plan: RasterPlan):
 # ---------------------------------------------------------------------------
 
 
+@scoped("raster")
 def rasterize(*args, **kwargs):
     """Rasterize projected Gaussians into (H,W,3) premultiplied color,
     (H,W) blended depth and (H,W) final transmittance (leading view axis

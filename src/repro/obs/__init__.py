@@ -1,6 +1,6 @@
 """SlamScope — zero-overhead telemetry for the RTGS serving stack.
 
-Three layers (see each module's docstring):
+Three layers (see each module's docstring), and the profiler's names:
 
 * :mod:`repro.obs.registry` — counters, gauges, log-bucketed latency
   histograms (mergeable, per-stream labels).
@@ -9,6 +9,8 @@ Three layers (see each module's docstring):
   Chrome-trace-event JSON export.
 * :mod:`repro.obs.hooks` — the :class:`Telemetry` sink protocol threaded
   through engine → session → server → benchmarks.
+* :mod:`repro.obs.profiling` — named scopes on the device work and build
+  counters, for profiler traces of the chip.
 
 The load-bearing invariant: telemetry rides data the host already has
 (wall-clock stamps, queue lengths, already-fetched ``DeviceWork``), so a

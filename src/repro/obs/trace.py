@@ -17,10 +17,10 @@ export`-s them as Chrome trace-event JSON.  A disabled recorder costs one
 attribute check per call — telemetry-off serving runs the identical code
 path (tests/test_obs.py holds the outputs bitwise-equal).
 
-:meth:`TraceRecorder.device_trace` is an optional passthrough to
-``jax.profiler.trace`` so a host-span trace can be correlated with a
-device-side profile of the same run; it is a no-op when profiling is
-unavailable (e.g. headless CI).
+Each span of an enabled recorder is also a ``jax.profiler.TraceAnnotation``
+named ``slam.<name>``: under a profiler session it lands in the
+``.xplane.pb`` on the profiler's clock, beside the device operations it
+dispatched.
 """
 
 from __future__ import annotations
@@ -29,6 +29,8 @@ import contextlib
 import json
 import time
 from typing import List, Optional
+
+import jax
 
 __all__ = ["now_s", "Stopwatch", "TraceRecorder"]
 
@@ -88,7 +90,8 @@ class TraceRecorder:
     def _span(self, name, tid, args):
         t0 = now_s()
         try:
-            yield self
+            with jax.profiler.TraceAnnotation("slam." + name):
+                yield self
         finally:
             self.events.append({
                 "ph": "X", "name": name, "pid": 0, "tid": tid,
@@ -124,21 +127,6 @@ class TraceRecorder:
         self.events.append({"ph": "f", "bp": "e", "name": name,
                             "id": flow_id, "cat": name, "pid": 0,
                             "tid": tid, "ts": self._ts()})
-
-    # -- device-side correlation ------------------------------------------
-
-    def device_trace(self, logdir: Optional[str]):
-        """Context manager wrapping ``jax.profiler.trace(logdir)`` when a
-        logdir is given and the profiler is importable; otherwise a no-op.
-        Lets one run produce both a host-span trace (this recorder) and a
-        device-side XLA profile over the same wall-clock window."""
-        if not (self.enabled and logdir):
-            return _NULL_CM
-        try:
-            import jax.profiler
-        except Exception:                       # pragma: no cover
-            return _NULL_CM
-        return jax.profiler.trace(logdir)
 
     # -- export ------------------------------------------------------------
 

@@ -37,6 +37,7 @@ these entry points; :func:`run_sequence` is the non-deprecated equivalent.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -84,6 +85,7 @@ from repro.slam.metrics import (
     wide_work_zero,
 )
 from repro.obs import Stopwatch, telemetry_or_off
+from repro.obs.profiling import scoped
 from repro.slam.map import paged as pagedmap
 from repro.train import optimizer as optim
 from repro.train.optimizer import Adam, AdamState
@@ -155,6 +157,7 @@ class SLAMResult:
         return float(np.mean(self.keyframe_psnr)) if self.keyframe_psnr else 0.0
 
 
+@functools.partial(jax.profiler.annotate_function, name="slam.seed_map")
 def _seed_map(dataset: SLAMDataset, cfg: SLAMConfig) -> G.GaussianField:
     """Bootstrap the map from frame 0's RGB-D (standard 3DGS-SLAM init)."""
     f0 = dataset.frames[0]
@@ -375,6 +378,7 @@ def _as_obs(frame) -> Observation:
 # ---------------------------------------------------------------------------
 
 
+@scoped("densify")
 def _densify_core(g: G.GaussianField, rgb, depth, rendered, w2c,
                   intr: Intrinsics, cfg: SLAMConfig, key):
     """Add Gaussians where the current render misses observed geometry.
@@ -459,15 +463,16 @@ def _make_row_step(meta: SessionMeta, factor: int):
         d_since = idx - sess.last_kf_idx
 
         # -- pre-tracking keyframe decision (gsslam re-decides after) ------
-        if kp.kind == "monogs":
-            pre_kf = d_since >= kp.interval
-        elif kp.kind == "splatam":
-            pre_kf = jnp.asarray(True)
-        elif kp.kind == "photoslam":
-            err = jnp.sqrt(jnp.mean((rgb - sess.last_kf_rgb) ** 2))
-            pre_kf = err > kp.pho_thresh
-        else:                                   # gsslam: post-tracking only
-            pre_kf = jnp.asarray(False)
+        with jax.named_scope("slam.keyframe"):
+            if kp.kind == "monogs":
+                pre_kf = d_since >= kp.interval
+            elif kp.kind == "splatam":
+                pre_kf = jnp.asarray(True)
+            elif kp.kind == "photoslam":
+                err = jnp.sqrt(jnp.mean((rgb - sess.last_kf_rgb) ** 2))
+                pre_kf = err > kp.pho_thresh
+            else:                               # gsslam: post-tracking only
+                pre_kf = jnp.asarray(False)
 
         base = sess.velocity @ sess.pose
 
@@ -482,67 +487,70 @@ def _make_row_step(meta: SessionMeta, factor: int):
         page = sess.page
         view_idx = None
         if paged is not None:
-            cams = jnp.concatenate([base[None], sess.kf_w2c], axis=0)
-            vis = pagedmap.pages_visible(page, intr, cams,
-                                         margin=paged.margin)
-            selected = pagedmap.select_pages(
-                vis, page.occupancy, paged.visible_pages,
-                priority=pagedmap.page_distances(page, base))
-            view_idx = pagedmap.view_rows(page.row2page, selected,
-                                          paged.page_capacity)
-            g_store, pstate_store = g, pstate
-            g = pagedmap.gather_field(g, view_idx)
-            if pstate is not None:
-                pstate = pruning.gather_rows(pstate, view_idx)
-                masked = pstate.masked
-            else:
-                masked = masked[view_idx]
-
-        obs_rgb = downsample_image(rgb, factor)
-        obs_depth = downsample_depth(depth, factor)
-        work0 = device_work_zero()
-        k_track = cfg.iters_track
+            with jax.named_scope("slam.paged"):
+                cams = jnp.concatenate([base[None], sess.kf_w2c], axis=0)
+                vis = pagedmap.pages_visible(page, intr, cams,
+                                             margin=paged.margin)
+                selected = pagedmap.select_pages(
+                    vis, page.occupancy, paged.visible_pages,
+                    priority=pagedmap.page_distances(page, base))
+                view_idx = pagedmap.view_rows(page.row2page, selected,
+                                              paged.page_capacity)
+                g_store, pstate_store = g, pstate
+                g = pagedmap.gather_field(g, view_idx)
+                if pstate is not None:
+                    pstate = pruning.gather_rows(pstate, view_idx)
+                    masked = pstate.masked
+                else:
+                    masked = masked[view_idx]
 
         # -- tracking: the PR 1/2 scan bundles as pure functions ----------
-        if cfg.base_algo == "photoslam":
-            pts_w, cols, _, valid = geometric.backproject_grid(
-                sess.prev_rgb, sess.prev_depth, sess.pose, intr, stride=4)
-            xi = geo_scan(base, pts_w, cols, valid, rgb, depth)
-            track_px = (intr.height // 4) * (intr.width // 4)
-            zero = jnp.asarray(0, jnp.int32)
-            work_t = DeviceWork(
-                fragments=zero,
-                pixels=jnp.asarray(track_px * k_track, jnp.int32),
-                gaussians_iters=zero,
-                iterations=jnp.asarray(k_track, jnp.int32),
-                unstable_gaussians=zero, sched_programs=zero,
-                skipped_fragments=zero, densify_dropped=zero,
-                frag_build_rows=zero)
-            track_losses = jnp.zeros((k_track,), jnp.float32)
-            fired = jnp.zeros((k_track,), bool)
-        else:
-            frags = st_t._build_core(g, masked, base)
-            if pstate is not None:
-                xi, g, pstate, work_t, track_losses, fired = \
-                    st_t._track_scan_prune(g, pstate, base, obs_rgb,
-                                           obs_depth, frags, work0)
-                masked = pstate.masked
+        with jax.named_scope("slam.track"):
+            obs_rgb = downsample_image(rgb, factor)
+            obs_depth = downsample_depth(depth, factor)
+            work0 = device_work_zero()
+            k_track = cfg.iters_track
+            if cfg.base_algo == "photoslam":
+                pts_w, cols, _, valid = geometric.backproject_grid(
+                    sess.prev_rgb, sess.prev_depth, sess.pose, intr, stride=4)
+                xi = geo_scan(base, pts_w, cols, valid, rgb, depth)
+                track_px = (intr.height // 4) * (intr.width // 4)
+                zero = jnp.asarray(0, jnp.int32)
+                work_t = DeviceWork(
+                    fragments=zero,
+                    pixels=jnp.asarray(track_px * k_track, jnp.int32),
+                    gaussians_iters=zero,
+                    iterations=jnp.asarray(k_track, jnp.int32),
+                    unstable_gaussians=zero, sched_programs=zero,
+                    skipped_fragments=zero, densify_dropped=zero,
+                    frag_build_rows=zero)
+                track_losses = jnp.zeros((k_track,), jnp.float32)
+                fired = jnp.zeros((k_track,), bool)
             else:
-                xi, work_t, track_losses, fired = st_t._track_scan_noprune(
-                    g, masked, base, obs_rgb, obs_depth, frags, work0)
+                frags = st_t._build_core(g, masked, base)
+                if pstate is not None:
+                    xi, g, pstate, work_t, track_losses, fired = \
+                        st_t._track_scan_prune(g, pstate, base, obs_rgb,
+                                               obs_depth, frags, work0)
+                    masked = pstate.masked
+                else:
+                    xi, work_t, track_losses, fired = \
+                        st_t._track_scan_noprune(g, masked, base, obs_rgb,
+                                                 obs_depth, frags, work0)
 
-        new_pose = lie.se3_exp(xi) @ base
+            new_pose = lie.se3_exp(xi) @ base
         velocity = new_pose @ jnp.linalg.inv(sess.pose)
         traj = sess.traj.at[idx].set(new_pose)
 
-        if kp.kind == "gsslam":
-            last_kf_pose = jax.lax.dynamic_index_in_dim(
-                sess.kf_w2c, sess.kf_count - 1, 0, keepdims=False)
-            rel = lie.se3_log(new_pose @ lie.se3_inverse(last_kf_pose))
-            is_kf = ((jnp.linalg.norm(rel[:3]) > kp.trans_thresh)
-                     | (jnp.linalg.norm(rel[3:]) > kp.rot_thresh))
-        else:
-            is_kf = pre_kf
+        with jax.named_scope("slam.keyframe"):
+            if kp.kind == "gsslam":
+                last_kf_pose = jax.lax.dynamic_index_in_dim(
+                    sess.kf_w2c, sess.kf_count - 1, 0, keepdims=False)
+                rel = lie.se3_log(new_pose @ lie.se3_inverse(last_kf_pose))
+                is_kf = ((jnp.linalg.norm(rel[:3]) > kp.trans_thresh)
+                         | (jnp.linalg.norm(rel[3:]) > kp.rot_thresh))
+            else:
+                is_kf = pre_kf
 
         # -- mapping (keyframes only) under lax.cond ----------------------
         key = jax.random.fold_in(sess.rng, idx)
@@ -628,7 +636,8 @@ def _make_row_step(meta: SessionMeta, factor: int):
                    (g, sess.map_opt, sess.kf_rgb, sess.kf_depth, sess.kf_w2c,
                     sess.kf_count, sess.kf_total, sess.kf_psnr, sess.frags,
                     sess.sched))
-        cond_out = jax.lax.cond(is_kf, map_branch, skip_branch, operand)
+        with jax.named_scope("slam.map"):
+            cond_out = jax.lax.cond(is_kf, map_branch, skip_branch, operand)
         if sparse:
             pstate = cond_out[-1]
             cond_out = cond_out[:-1]
@@ -637,19 +646,22 @@ def _make_row_step(meta: SessionMeta, factor: int):
 
         # -- PagedMap scatter-back + keyframe page-table rebuild -----------
         if paged is not None:
-            g = pagedmap.scatter_field(g_store, g, view_idx)
-            if pstate is not None:
-                pstate = pruning.scatter_rows(pstate_store, pstate, view_idx)
-            # Rebuild the spatial index on keyframes (the only step that
-            # admits rows): densified newcomers migrate from nursery pages
-            # to their Morton bucket and dead rows re-collect page-locally.
-            # Between keyframes the stale table is conservative — pruning
-            # removals only shrink true AABBs/occupancy, never grow them.
-            page = jax.lax.cond(
-                is_kf,
-                lambda gg: pagedmap.build_page_table(gg, paged),
-                lambda gg: page,
-                g)
+            with jax.named_scope("slam.paged"):
+                g = pagedmap.scatter_field(g_store, g, view_idx)
+                if pstate is not None:
+                    pstate = pruning.scatter_rows(pstate_store, pstate,
+                                                  view_idx)
+                # Rebuild the spatial index on keyframes (the only step
+                # that admits rows): densified newcomers migrate from
+                # nursery pages to their Morton bucket and dead rows
+                # re-collect page-locally.  Between keyframes the stale
+                # table is conservative — pruning removals only shrink true
+                # AABBs/occupancy, never grow them.
+                page = jax.lax.cond(
+                    is_kf,
+                    lambda gg: pagedmap.build_page_table(gg, paged),
+                    lambda gg: page,
+                    g)
 
         alive_now = g.num_alive()
         step_work = device_work_merge(work_t, work_m)
@@ -715,6 +727,7 @@ def _step_fn(meta: SessionMeta, factor: int, batch: Optional[int]):
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.profiler.annotate_function, name="slam.session_init")
 def session_init(dataset: SLAMDataset, cfg: SLAMConfig, *,
                  max_frames: Optional[int] = None, seed: int = 0,
                  stats: Optional[EngineStats] = None) -> SlamSession:
@@ -763,9 +776,10 @@ def session_init(dataset: SLAMDataset, cfg: SLAMConfig, *,
     if stats is not None:
         stats.dispatches += 1
     map_opt0 = Adam(lr=cfg.lr_map).init(G.params_of(g))
-    g, map_opt, work_m, psnr0, alive0, frags_l, sched_l = boot(
-        g, masked if pstate is None else pstate.masked, map_opt0,
-        kf_w2c, kf_rgb, kf_depth, kf_valid)
+    with jax.profiler.TraceAnnotation("slam.boot"):
+        g, map_opt, work_m, psnr0, alive0, frags_l, sched_l = boot(
+            g, masked if pstate is None else pstate.masked, map_opt0,
+            kf_w2c, kf_rgb, kf_depth, kf_valid)
 
     # PagedMap: the bootstrap mapped the full pool (frame 0 sees the whole
     # seed map); build the initial spatial index and park the Adam moments
@@ -831,6 +845,7 @@ def _boot_fn(meta: SessionMeta):
     return _BOOT_CACHE[key]
 
 
+@functools.partial(jax.profiler.annotate_function, name="slam.step")
 def session_step(session: SlamSession, frame, *, factor: int = 1,
                  stats: Optional[EngineStats] = None
                  ) -> Tuple[SlamSession, StepResult]:

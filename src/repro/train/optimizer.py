@@ -14,6 +14,8 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profiling import scoped
+
 
 class AdamState(NamedTuple):
     step: jnp.ndarray
@@ -41,6 +43,7 @@ class Adam:
     def _lr(self, step):
         return self.lr(step) if callable(self.lr) else self.lr
 
+    @scoped("adam")
     def update(self, grads, state: AdamState, params=None):
         """Dtype-preserving update: every tensor op stays in the leaf's own
         dtype (bf16 moments in -> bf16 moments out). Mixing in f32 scalars
@@ -75,6 +78,7 @@ class Adam:
             updates = jax.tree.map(upd, mu, nu, params)
         return updates, AdamState(step=step, mu=mu, nu=nu)
 
+    @scoped("adam")
     def update_masked(self, grads, state: AdamState, row_mask, params=None):
         """Row-masked :meth:`update` for the sparse stable/unstable path:
         rows where ``row_mask`` is False (stable Gaussians) get a zero
@@ -144,10 +148,12 @@ def _row_mask(mask, x):
     return mask.reshape(mask.shape + (1,) * (x.ndim - 1))
 
 
+@scoped("adam")
 def apply_updates(params, updates):
     return jax.tree.map(lambda p, u: p + u.astype(p.dtype), params, updates)
 
 
+@scoped("adam")
 def apply_updates_masked(params, updates, row_mask):
     """:func:`apply_updates` restricted to rows where ``row_mask`` is True.
 
