@@ -26,6 +26,11 @@ import jax
 import jax.numpy as jnp
 
 
+def dot3(a, b) -> jnp.ndarray:
+    """Dot product of two 3-vectors given as triples of (N,) components."""
+    return a[0] * b[0] + a[1] * b[1] + a[2] * b[2]
+
+
 class GaussianField(NamedTuple):
     mu: jnp.ndarray        # (N, 3) float32
     log_scale: jnp.ndarray  # (N, 3) float32
@@ -50,25 +55,25 @@ class GaussianField(NamedTuple):
     def scales(self) -> jnp.ndarray:
         return jnp.exp(self.log_scale)
 
-    def rotations(self) -> jnp.ndarray:
-        """Unit quaternions -> (N,3,3) rotation matrices."""
-        q = self.quat / (jnp.linalg.norm(self.quat, axis=-1, keepdims=True) + 1e-9)
-        w, x, y, z = q[:, 0], q[:, 1], q[:, 2], q[:, 3]
-        return jnp.stack(
-            [
-                jnp.stack([1 - 2 * (y * y + z * z), 2 * (x * y - w * z), 2 * (x * z + w * y)], -1),
-                jnp.stack([2 * (x * y + w * z), 1 - 2 * (x * x + z * z), 2 * (y * z - w * x)], -1),
-                jnp.stack([2 * (x * z - w * y), 2 * (y * z + w * x), 1 - 2 * (x * x + y * y)], -1),
-            ],
-            axis=-2,
-        )
+    def covariance_terms(self) -> tuple[jnp.ndarray, ...]:
+        """3D covariance Sigma = (R S)(R S)^T as its six unique entries.
 
-    def covariance(self) -> jnp.ndarray:
-        """3D covariance Sigma = R S S^T R^T, (N,3,3)."""
-        R = self.rotations()
-        S = self.scales()
-        RS = R * S[:, None, :]
-        return RS @ jnp.swapaxes(RS, -1, -2)
+        Returns ``(xx, xy, xz, yy, yz, zz)``, each (N,), with R the rotation
+        of the normalised quaternion and S = diag(exp(log_scale)). Written
+        out as f32 multiply-adds on per-Gaussian components: a batched
+        (N,3,3) matmul would run as multi-pass MXU work on 3x3 tiles.
+        """
+        w, x, y, z = (self.quat[:, i] for i in range(4))
+        n = jnp.sqrt(w * w + x * x + y * y + z * z) + 1e-9
+        w, x, y, z = w / n, x / n, y / n, z / n
+        s = self.scales()
+        sx, sy, sz = s[:, 0], s[:, 1], s[:, 2]
+        # Rows of M = R S (column k of R scaled by s_k).
+        m0 = ((1 - 2 * (y * y + z * z)) * sx, 2 * (x * y - w * z) * sy, 2 * (x * z + w * y) * sz)
+        m1 = (2 * (x * y + w * z) * sx, (1 - 2 * (x * x + z * z)) * sy, 2 * (y * z - w * x) * sz)
+        m2 = (2 * (x * z - w * y) * sx, 2 * (y * z + w * x) * sy, (1 - 2 * (x * x + y * y)) * sz)
+        return (dot3(m0, m0), dot3(m0, m1), dot3(m0, m2),
+                dot3(m1, m1), dot3(m1, m2), dot3(m2, m2))
 
 
 PARAM_FIELDS = ("mu", "log_scale", "quat", "logit_o", "color")

@@ -12,7 +12,7 @@ from typing import NamedTuple
 import jax.numpy as jnp
 
 from repro.core.camera import Camera
-from repro.core.gaussians import GaussianField
+from repro.core.gaussians import GaussianField, dot3
 from repro.obs.profiling import scoped
 
 # Low-pass filter added to 2D covariance (standard 3DGS; guarantees a
@@ -35,63 +35,63 @@ class ProjectedGaussians(NamedTuple):
 
 @scoped("project")
 def project(g: GaussianField, cam: Camera) -> ProjectedGaussians:
+    """EWA projection, every 3x3 and 2x3 product written out as f32
+    multiply-adds on (N,) component vectors: no (N,3,3) intermediate and no
+    ``dot_general``, forward or backward (the pose gradient is a sum over N).
+    """
     intr = cam.intrinsics
     W = cam.w2c[:3, :3]
     t = cam.w2c[:3, 3]
 
-    p_cam = g.mu @ W.T + t  # (N,3)
-    z = p_cam[:, 2]
+    mx, my, mz = g.mu[:, 0], g.mu[:, 1], g.mu[:, 2]
+    px, py, z = (W[i, 0] * mx + W[i, 1] * my + W[i, 2] * mz + t[i] for i in range(3))
     z_safe = jnp.maximum(z, _NEAR)
 
-    mu2d = jnp.stack(
-        [
-            intr.fx * p_cam[:, 0] / z_safe + intr.cx,
-            intr.fy * p_cam[:, 1] / z_safe + intr.cy,
-        ],
-        axis=-1,
-    )
+    u = intr.fx * px / z_safe + intr.cx
+    v = intr.fy * py / z_safe + intr.cy
 
-    # Perspective Jacobian J (N,2,3).
+    # Rows a, b of JW, with the perspective Jacobian
+    # J = [[fx/z, 0, -fx x/z^2], [0, fy/z, -fy y/z^2]].
     inv_z = 1.0 / z_safe
     inv_z2 = inv_z * inv_z
-    zeros = jnp.zeros_like(z)
-    J = jnp.stack(
-        [
-            jnp.stack([intr.fx * inv_z, zeros, -intr.fx * p_cam[:, 0] * inv_z2], -1),
-            jnp.stack([zeros, intr.fy * inv_z, -intr.fy * p_cam[:, 1] * inv_z2], -1),
-        ],
-        axis=-2,
-    )
+    j00, j02 = intr.fx * inv_z, -intr.fx * px * inv_z2
+    j11, j12 = intr.fy * inv_z, -intr.fy * py * inv_z2
+    a = tuple(j00 * W[0, k] + j02 * W[2, k] for k in range(3))
+    b = tuple(j11 * W[1, k] + j12 * W[2, k] for k in range(3))
 
-    cov3d = g.covariance()  # (N,3,3)
-    JW = J @ W  # (N,2,3)
-    cov2d = JW @ cov3d @ jnp.swapaxes(JW, -1, -2)  # (N,2,2)
-    cov2d = cov2d + _COV2D_BLUR * jnp.eye(2, dtype=cov2d.dtype)
+    # cov2d = JW Sigma JW^T + blur I, as its three unique entries.
+    sxx, sxy, sxz, syy, syz, szz = g.covariance_terms()
 
-    det = cov2d[:, 0, 0] * cov2d[:, 1, 1] - cov2d[:, 0, 1] * cov2d[:, 1, 0]
+    def sigma_times(r):
+        return (sxx * r[0] + sxy * r[1] + sxz * r[2],
+                sxy * r[0] + syy * r[1] + syz * r[2],
+                sxz * r[0] + syz * r[1] + szz * r[2])
+
+    sa = sigma_times(a)
+    c00 = dot3(a, sa) + _COV2D_BLUR
+    c01 = dot3(b, sa)
+    c11 = dot3(b, sigma_times(b)) + _COV2D_BLUR
+
+    det = c00 * c11 - c01 * c01
     det_safe = jnp.maximum(det, 1e-12)
     inv_det = 1.0 / det_safe
-    conic = jnp.stack(
-        [cov2d[:, 1, 1] * inv_det, -cov2d[:, 0, 1] * inv_det, cov2d[:, 0, 0] * inv_det],
-        axis=-1,
-    )
+    conic = jnp.stack([c11 * inv_det, -c01 * inv_det, c00 * inv_det], axis=-1)
 
     # Screen-space radius: 3 sigma of the major axis (non-differentiable use).
-    mid = 0.5 * (cov2d[:, 0, 0] + cov2d[:, 1, 1])
+    mid = 0.5 * (c00 + c11)
     lam1 = mid + jnp.sqrt(jnp.maximum(mid * mid - det_safe, 0.0) + 1e-12)
     radius = jnp.ceil(3.0 * jnp.sqrt(jnp.maximum(lam1, 0.0)))
 
-    margin = radius
     onscreen = (
-        (mu2d[:, 0] + margin >= 0.0)
-        & (mu2d[:, 0] - margin <= intr.width)
-        & (mu2d[:, 1] + margin >= 0.0)
-        & (mu2d[:, 1] - margin <= intr.height)
+        (u + radius >= 0.0)
+        & (u - radius <= intr.width)
+        & (v + radius >= 0.0)
+        & (v - radius <= intr.height)
     )
     valid = g.alive & (z > _NEAR) & (det > 1e-12) & onscreen
 
     return ProjectedGaussians(
-        mu2d=mu2d,
+        mu2d=jnp.stack([u, v], axis=-1),
         conic=conic,
         color=g.rgb(),
         opacity=g.opacity(),
