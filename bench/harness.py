@@ -14,6 +14,7 @@ same mix of tracking-only frames and keyframes.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import importlib
 import time
@@ -66,23 +67,22 @@ def first_frame(stream: traffic.Stream, cfg: dict):
 
 
 def record(res, row=None) -> dict:
-    """One stream's step result on the host."""
+    """One stream's step result on the host; ``work`` holds every counter
+    of the step's ``DeviceWork``, by its field name."""
     pick = (lambda x: x) if row is None else (lambda x: x[row])
     return {"pose": np.asarray(pick(res["pose"]), np.float64),
             "is_kf": bool(pick(res["is_kf"])),
             "track_losses": np.asarray(pick(res["track_losses"]), np.float64),
             "map_losses": np.asarray(pick(res["map_losses"]), np.float64),
             "psnr": float(pick(res["psnr"])),
-            "fragments": int(pick(res["fragments"])),
-            "pixels": int(pick(res["pixels"]))}
+            "work": {k: int(pick(v)) for k, v in res["work"].items()}}
 
 
 def fetch(out):
     return jax.device_get({"pose": out.pose, "is_kf": out.is_kf,
                            "track_losses": out.track_losses,
                            "map_losses": out.map_losses, "psnr": out.psnr,
-                           "fragments": out.work.fragments,
-                           "pixels": out.work.pixels})
+                           "work": out.work._asdict()})
 
 
 def system(streams, cfg: dict, session_seed: int, max_frames: int):
@@ -102,8 +102,10 @@ class Window:
     periods: int = 0
     latencies_ms: list = dataclasses.field(default_factory=list)
     failed: int = 0
-    work: dict = dataclasses.field(
-        default_factory=lambda: {"fragments": 0, "pixels": 0})
+    # Every DeviceWork counter summed over the window's frames (a counter
+    # the window never saw reads 0).
+    work: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter)
     records: list = dataclasses.field(default_factory=list)
 
 
@@ -130,7 +132,7 @@ def measure(system, seconds: float, period_max: int, annotate: bool,
     ``keep`` frame-steps are kept for the check."""
     w = Window()
     pending_lat, pending_failed, since_kf = [], 0, 0
-    pending_work = {"fragments": 0, "pixels": 0}
+    pending_work = collections.Counter()
     t0 = time.perf_counter()
     while True:
         recs, lat = step_once(system, annotate)
@@ -139,8 +141,8 @@ def measure(system, seconds: float, period_max: int, annotate: bool,
         since_kf += 1
         pending_lat += [lat * 1e3] * len(recs)
         pending_failed += sum(not np.isfinite(r["pose"]).all() for r in recs)
-        for k in pending_work:
-            pending_work[k] += sum(r[k] for r in recs)
+        for r in recs:
+            pending_work.update(r["work"])
         kf = [r["is_kf"] for r in recs]
         if since_kf > period_max or any(kf) != all(kf):
             # No period closes (a stream keyframes off its interval): the
@@ -160,9 +162,8 @@ def measure(system, seconds: float, period_max: int, annotate: bool,
         w.steps += since_kf
         w.frames += len(pending_lat)
         w.failed += pending_failed
-        for k in pending_work:
-            w.work[k] += pending_work[k]
-            pending_work[k] = 0
+        w.work.update(pending_work)
+        pending_work.clear()
         pending_lat, pending_failed, since_kf = [], 0, 0
         if elapsed + elapsed / w.periods > seconds:
             return w

@@ -105,8 +105,10 @@ class RunData:
     trace: object
     frames: int
     steps: int
-    work: dict
+    work: dict         # every DeviceWork counter, summed over the window
     peaks: dict
+    counters: dict     # the program's build counters: "setup", the totals
+    #                    at the end of set-up; "window", what it added
 
 
 def memory_peak(stats: dict) -> int:
@@ -128,6 +130,8 @@ def run(args, root: str = ROOT, hooks=None) -> dict:
     """One run of one cell; returns the result object.  ``hooks`` (tests
     only) may replace the system's step underneath the harness."""
     import jax
+    # Imported before anything compiles: its listener counts every build.
+    from repro.obs.profiling import build_counters
 
     from bench import compare, harness, tracing, traffic
 
@@ -150,7 +154,10 @@ def run(args, root: str = ROOT, hooks=None) -> dict:
     log("system started")
     warm = harness.warm_up(system, warm_frames)
     setup_s = time.perf_counter() - T0
-    log(f"warm-up period done: set-up {setup_s:.2f} s")
+    setup_builds = build_counters()
+    log(f"warm-up period done: set-up {setup_s:.2f} s, "
+        f"{setup_builds['jit_s']:.2f} s of it building "
+        f"{setup_builds['programs']} program(s)")
 
     log_dir = None
     if args.trace:
@@ -165,6 +172,10 @@ def run(args, root: str = ROOT, hooks=None) -> dict:
     finally:
         if args.trace:
             jax.profiler.stop_trace()
+    after = build_counters()
+    builds = {"setup": setup_builds,
+              "window": {k: after[k] - v for k, v in setup_builds.items()}}
+    log(f"window built {builds['window']['programs']} program(s)")
     steps = harness.complete(system, warm + w.records, checked)
     peak_bytes = memory_peak(dev.memory_stats() or {})
     system.close()
@@ -188,7 +199,7 @@ def run(args, root: str = ROOT, hooks=None) -> dict:
         w0, w1 = tr.window()
         device.update(busy_s=tracing.busy_ns(tr) * 1e-9, window_s=(w1 - w0) * 1e-9)
         data = RunData(trace=tr, frames=w.frames, steps=w.steps, work=w.work,
-                       peaks=peaks)
+                       peaks=peaks, counters=builds)
         metrics = {}
         for m in cell.per_layer:
             value = spec.load_reader(m["name"])(data)
@@ -209,7 +220,9 @@ def run(args, root: str = ROOT, hooks=None) -> dict:
                              for m in cell.end_to_end}
     result["device"] = device
     result["window"] = {"seconds": w.seconds, "periods": w.periods,
-                        "frames": w.frames, "steps": w.steps}
+                        "frames": w.frames, "steps": w.steps,
+                        "work": dict(w.work)}
+    result["builds"] = builds
     result["compared"] = compare.shown(nums, limits)
     for line in lines:
         print(f"compared: {line}", file=sys.stderr)

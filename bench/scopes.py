@@ -6,8 +6,7 @@ The program names its device work with ``jax.named_scope``
 work scopes of the functions that do the work (``WORK``).  A TPU profile
 keeps each device operation's scope path as the ``tf_op`` stat of the
 operation's metadata in the ``.xplane.pb``, and its source line as
-``source``.  ``jax.profiler.ProfileData`` does not expose metadata stats,
-so :func:`op_metadata` reads them from the file's protobuf itself.
+``source``; ``bench/tracing.py`` loads them with each operation.
 
 Each operation goes to its innermost work scope.  Where XLA dropped the
 path (an operation that layout assignment or a loop's carry made, or one
@@ -29,15 +28,15 @@ at the end of set-up and over the window.
 
     python3 bench/scopes.py --trace-dir <dir> [--frames <n>]
 
-reduces a kept profile alone.  No cell runs this: it is the reduction a
-per-layer metric of each scope would read.
+reduces a kept profile alone.  The per-layer metrics of the work buckets
+and the phases (``bench/metrics/``) read the same reduction through
+:func:`split`.
 """
 
 from __future__ import annotations
 
 import argparse
-import dataclasses
-import glob
+import functools
 import json
 import os
 import re
@@ -69,103 +68,12 @@ SOURCES = {"core/projection.py": "project", "core/sorting.py": "frag_build",
 SPAN_STEP = "slam.step"
 
 
-# -- the .xplane.pb's metadata ----------------------------------------------
-
-def _varint(buf, i: int):
-    out = shift = 0
-    while True:
-        b = buf[i]
-        i += 1
-        out |= (b & 0x7F) << shift
-        if b < 0x80:
-            return out, i
-        shift += 7
-
-
-def _fields(buf):
-    """(field number, value) of one protobuf message: an int for varint and
-    fixed-width fields, a memoryview for length-delimited ones."""
-    i, n = 0, len(buf)
-    while i < n:
-        key, i = _varint(buf, i)
-        kind = key & 7
-        if kind == 0:
-            val, i = _varint(buf, i)
-        elif kind == 2:
-            size, i = _varint(buf, i)
-            val, i = buf[i:i + size], i + size
-        elif kind == 1:
-            val, i = int.from_bytes(buf[i:i + 8], "little"), i + 8
-        elif kind == 5:
-            val, i = int.from_bytes(buf[i:i + 4], "little"), i + 4
-        else:
-            raise ValueError(f"protobuf wire type {kind} is not read here")
-        yield key >> 3, val
-
-
-def _map_entry(buf):
-    key = val = None
-    for f, v in _fields(buf):
-        if f == 1:
-            key = v
-        elif f == 2:
-            val = v
-    return key, val
-
-
-def op_metadata(path: str) -> dict:
-    """``{device plane: {operation's full name: (tf_op, source)}}`` from the
-    ``XEventMetadata`` of each ``/device:TPU:<n>`` plane of an XSpace.
-
-    XSpace.planes = 1; XPlane: name 2, event_metadata 4, stat_metadata 5
-    (maps: key 1, value 2); XEventMetadata: name 2, stats 5;
-    XStatMetadata: name 2; XStat: metadata_id 1, str_value 5, ref_value 7
-    (the id of a stat metadata whose name is the value)."""
-    with open(path, "rb") as f:
-        space = memoryview(f.read())
-    out = {}
-    for field, plane in _fields(space):
-        if field != 1:
-            continue
-        name, events, stat_names = "", [], {}
-        for f, v in _fields(plane):
-            if f == 2:
-                name = bytes(v).decode()
-            elif f == 4:
-                events.append(_map_entry(v)[1])
-            elif f == 5:
-                key, meta = _map_entry(v)
-                stat_names[key] = next((bytes(x).decode() for g, x in
-                                        _fields(meta) if g == 2), "")
-        if not re.fullmatch(r"/device:TPU:\d+", name):
-            continue
-        ops = {}
-        for ev in events:
-            op, stats = "", {}
-            for f, v in _fields(ev):
-                if f == 2:
-                    op = bytes(v).decode()
-                elif f == 5:
-                    sid, val = None, ""
-                    for g, x in _fields(v):
-                        if g == 1:
-                            sid = x
-                        elif g == 5:
-                            val = bytes(x).decode()
-                        elif g == 7:
-                            val = stat_names.get(x, "")
-                    stats[stat_names.get(sid)] = val
-            ops[op] = (stats.get("tf_op", ""), stats.get("source", ""))
-        out[name] = ops
-    return out
-
-
 # -- scope paths ------------------------------------------------------------
 
-@dataclasses.dataclass(frozen=True)
-class ScopedEvent(tracing.Event):
-    scope: str = ""         # the op_name (``tf_op``) path
-    source: str = ""        # "<file>:<line>" of the user code that made it
+# The reader of the scope metadata and the event that carries it live in
+# ``bench/tracing.py``; these names are kept for the reduction's callers.
+op_metadata = tracing.op_metadata
+ScopedEvent = tracing.Event
 
 
 def components(path: str) -> list:
@@ -242,39 +150,6 @@ def attribute(ops) -> list:
 
 # -- the reduction ----------------------------------------------------------
 
-def load(log_dir: str) -> tracing.Trace:
-    """The newest profile under ``log_dir`` as a :class:`tracing.Trace`
-    whose device operations are :class:`ScopedEvent`s and whose host spans
-    are the benchmark's (``bench.*``) and the program's (``slam.*``)."""
-    from jax.profiler import ProfileData
-
-    paths = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
-                             recursive=True), key=os.path.getmtime)
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
-    meta = op_metadata(paths[-1])
-    data = ProfileData.from_file(paths[-1])
-    devices, host = [], []
-    for plane in data.planes:
-        if plane.name in meta:
-            names = meta[plane.name]
-            ops = []
-            for line in plane.lines:
-                if line.name == tracing.OP_LINE:
-                    for e in line.events:
-                        scope, source = names.get(e.name, ("", ""))
-                        ops.append(ScopedEvent(
-                            e.start_ns, e.duration_ns, tracing.op_name(e.name),
-                            scope, source))
-            devices.append(ops)
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                host += [tracing.Event(e.start_ns, e.duration_ns, e.name)
-                         for e in line.events
-                         if e.name.startswith(("bench.", "slam."))]
-    return tracing.Trace(devices=devices, host=host)
-
-
 def device_split(trace: tracing.Trace, frames: int) -> dict | None:
     """The window's device time in ms/frame, averaged over the chips:
     ``work`` by bucket (``BUCKETS``, then ``step_unscoped``: the device
@@ -348,42 +223,37 @@ def reduce(trace: tracing.Trace, frames: int | None = None) -> dict:
             "host": host}
 
 
+@functools.lru_cache(maxsize=1)
+def _split(trace: tracing.Trace, frames: int) -> dict | None:
+    if not any(e.scope for ops in trace.devices for e in ops):
+        return None
+    return device_split(trace, frames)
+
+
+def split(run) -> dict | None:
+    """:func:`device_split` of a per-layer reader's run, reduced once for
+    all the readers of one trace; None where no device operation carries a
+    scope path, as in a profile without the program's metadata: nothing is
+    there to attribute."""
+    return _split(run.trace, run.frames)
+
+
 # -- one traced run with the build counters ---------------------------------
 
 def traced_run(workload: str, seed: int, seconds: float, out: str,
                rehearse: bool = False) -> dict:
     """One ``--trace 1`` run of ``workload`` (its profile kept under
-    ``out``), reduced, with the program's build counters: ``setup`` at the
-    first timed dispatch, ``window`` summed over the timed dispatches."""
-    from repro.obs.profiling import build_counters
-
+    ``out``), reduced, with the program's build counters as the harness
+    takes them: ``setup`` at the end of set-up, ``window`` over the window."""
     from bench import run as bench_run
-    from bench import spec
-
-    warm = spec.load_cell(workload).traffic["warmup_frames"]
-    calls = []                         # (before, after) of every dispatch
-
-    def count_builds(system):
-        dispatch = system.dispatch
-
-        def counted():
-            before = build_counters()
-            dispatch()
-            calls.append((before, build_counters()))
-        system.dispatch = counted
 
     args = argparse.Namespace(workload=workload, seed=seed, seconds=seconds,
                               trace=1, rehearse=rehearse, keep_trace=out)
-    result = bench_run.run(args, hooks=count_builds)
-    timed = calls[warm:warm + result["window"]["steps"]]
-    setup = calls[warm][0]
-    window = {k: sum(a[k] - b[k] for b, a in timed) for k in setup}
-    bench_run.log(f"window built {window['programs']} program(s); set-up "
-                  f"spent {setup['jit_s']:.2f} s tracing, lowering, "
-                  "compiling or loading")
+    result = bench_run.run(args)
     return {"result": result,
-            "split": reduce(load(out), result["window"]["frames"]),
-            "builds": {"setup": setup, "window": window}}
+            "split": reduce(tracing.load_xplane(out),
+                            result["window"]["frames"]),
+            "builds": result["builds"]}
 
 
 def main(argv=None) -> int:
@@ -397,7 +267,7 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     if args.trace_dir:
-        out = reduce(load(args.trace_dir), args.frames)
+        out = reduce(tracing.load_xplane(args.trace_dir), args.frames)
     else:
         if not (args.workload and args.seed is not None and args.seconds
                 and args.out):

@@ -1,9 +1,10 @@
 """Reduction of a profiler trace to busy time, kernel time and idle gaps.
 
 The profiler writes an ``.xplane.pb``; :func:`load_xplane` turns it into a
-:class:`Trace`: the device's operations (one list per chip) and the
-benchmark's own host spans, on one clock.  Everything else works on that
-plain form, so it can be checked on small made-up traces.
+:class:`Trace`: the device's operations (one list per chip), each with the
+scope path and source line that the program's metadata gives it, and the
+benchmark's and the program's host spans, on one clock.  Everything else
+works on that plain form, so it can be checked on small made-up traces.
 """
 
 from __future__ import annotations
@@ -21,13 +22,15 @@ class Event:
     start_ns: float
     dur_ns: float
     name: str               # the HLO instruction's name, e.g. "fusion.12"
+    scope: str = ""         # its op_name (``tf_op``) path
+    source: str = ""        # "<file>:<line>" of the user code that made it
 
     @property
     def end_ns(self) -> float:
         return self.start_ns + self.dur_ns
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)          # compared and hashed by identity
 class Trace:
     devices: list           # per chip, a list of device-operation Events
     host: list              # the benchmark's own host spans (Events)
@@ -57,29 +60,135 @@ def is_container(name: str) -> bool:
     return name.split(".", 1)[0] in CONTAINERS
 
 
+# -- the .xplane.pb's metadata ----------------------------------------------
+#
+# ``jax.profiler.ProfileData`` does not expose the stats of an event's
+# metadata, where a TPU profile keeps each operation's scope path (``tf_op``)
+# and source line (``source``), so :func:`op_metadata` reads them from the
+# file's protobuf itself.
+
+def _varint(buf, i: int):
+    out = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        out |= (b & 0x7F) << shift
+        if b < 0x80:
+            return out, i
+        shift += 7
+
+
+def _fields(buf):
+    """(field number, value) of one protobuf message: an int for varint and
+    fixed-width fields, a memoryview for length-delimited ones."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        kind = key & 7
+        if kind == 0:
+            val, i = _varint(buf, i)
+        elif kind == 2:
+            size, i = _varint(buf, i)
+            val, i = buf[i:i + size], i + size
+        elif kind == 1:
+            val, i = int.from_bytes(buf[i:i + 8], "little"), i + 8
+        elif kind == 5:
+            val, i = int.from_bytes(buf[i:i + 4], "little"), i + 4
+        else:
+            raise ValueError(f"protobuf wire type {kind} is not read here")
+        yield key >> 3, val
+
+
+def _map_entry(buf):
+    key = val = None
+    for f, v in _fields(buf):
+        if f == 1:
+            key = v
+        elif f == 2:
+            val = v
+    return key, val
+
+
+def is_device_plane(name: str) -> bool:
+    return re.fullmatch(r"/device:TPU:\d+", name) is not None
+
+
+def op_metadata(path: str) -> dict:
+    """``{device plane: {operation's full name: (tf_op, source)}}`` from the
+    ``XEventMetadata`` of each ``/device:TPU:<n>`` plane of an XSpace.
+
+    XSpace.planes = 1; XPlane: name 2, event_metadata 4, stat_metadata 5
+    (maps: key 1, value 2); XEventMetadata: name 2, stats 5;
+    XStatMetadata: name 2; XStat: metadata_id 1, str_value 5, ref_value 7
+    (the id of a stat metadata whose name is the value)."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out = {}
+    for field, plane in _fields(space):
+        if field != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for f, v in _fields(plane):
+            if f == 2:
+                name = bytes(v).decode()
+            elif f == 4:
+                events.append(_map_entry(v)[1])
+            elif f == 5:
+                key, meta = _map_entry(v)
+                stat_names[key] = next((bytes(x).decode() for g, x in
+                                        _fields(meta) if g == 2), "")
+        if not is_device_plane(name):
+            continue
+        ops = {}
+        for ev in events:
+            op, stats = "", {}
+            for f, v in _fields(ev):
+                if f == 2:
+                    op = bytes(v).decode()
+                elif f == 5:
+                    sid, val = None, ""
+                    for g, x in _fields(v):
+                        if g == 1:
+                            sid = x
+                        elif g == 5:
+                            val = bytes(x).decode()
+                        elif g == 7:
+                            val = stat_names.get(x, "")
+                    stats[stat_names.get(sid)] = val
+            ops[op] = (stats.get("tf_op", ""), stats.get("source", ""))
+        out[name] = ops
+    return out
+
+
 def load_xplane(log_dir: str) -> Trace:
-    """The device operations of every ``/device:TPU:<n>`` plane and the
-    ``bench.*`` host spans from the newest ``.xplane.pb`` under ``log_dir``."""
+    """The device operations of every ``/device:TPU:<n>`` plane, each with
+    its scope path and source (:func:`op_metadata`), and the host spans of
+    the benchmark (``bench.*``) and of the program (``slam.*``) from the
+    newest ``.xplane.pb`` under ``log_dir``."""
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
+    meta = op_metadata(paths[-1])
     data = ProfileData.from_file(paths[-1])
     devices, host = [], []
     for plane in data.planes:
-        if re.fullmatch(r"/device:TPU:\d+", plane.name):
+        if is_device_plane(plane.name):
+            names = meta.get(plane.name, {})
             ops = []
             for line in plane.lines:
                 if line.name == OP_LINE:
-                    ops += [Event(e.start_ns, e.duration_ns, op_name(e.name))
+                    ops += [Event(e.start_ns, e.duration_ns, op_name(e.name),
+                                  *names.get(e.name, ("", "")))
                             for e in line.events]
             devices.append(ops)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 host += [Event(e.start_ns, e.duration_ns, e.name)
-                         for e in line.events if e.name.startswith("bench.")]
+                         for e in line.events
+                         if e.name.startswith(("bench.", "slam."))]
     return Trace(devices=devices, host=host)
 
 

@@ -32,22 +32,45 @@ def test_every_cell_loads_by_name(cell):
     assert c.per_layer
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
-def test_every_metric_reader_loads_and_reads(metric):
-    read = spec.load_reader(metric)
-    tr = Trace(devices=[[Event(0, 400, "fusion.0"),
-                         Event(400, 300, "jvp_jit_tile_render_fwd_sched__.1"),
-                         Event(700, 200,
-                               "transpose_jvp_jit_tile_render_bwd_sched___.2")]],
-               host=[Event(0, 1000, "bench.window"),
-                     Event(0, 10, "bench.dispatch")])
+TRACK = "jit(solo)/slam.track/while/body"
+MAP = "jit(solo)/jvp(slam.map)/cond/branch_1_fun"
+MAP_T = "jit(solo)/transpose(jvp(slam.map))/cond/branch_1_fun"
 
+
+def scoped_trace():
+    """A window of 1,000 ns: 400 ns of scoped XLA work in both phases,
+    then the forward kernel (tracking) and the backward one (mapping)."""
+    return Trace(
+        devices=[[
+            Event(0, 100, "fusion.0", TRACK + "/project/mul:"),
+            Event(100, 100, "fusion.1", TRACK + "/frag_build/add:"),
+            Event(200, 100, "fusion.2", MAP + "/raster/gather:"),
+            Event(300, 50, "fusion.3", MAP + "/adam/mul:"),
+            Event(350, 25, "fusion.4", TRACK + "/wsu_schedule/sort:"),
+            Event(375, 25, "copy.5", "jit(solo)/copy:"),    # no scope, no phase
+            Event(400, 300, "jvp_jit_tile_render_fwd_sched__.1",
+                  TRACK + "/raster/jit(tile_render_fwd_sched)/pallas_call:"),
+            Event(700, 200, "transpose_jvp_jit_tile_render_bwd_sched___.2",
+                  MAP_T + "/raster/jit(tile_render_bwd_sched)/pallas_call:")]],
+        host=[Event(0, 1000, "bench.window"), Event(0, 10, "bench.dispatch"),
+              Event(2, 6, "slam.step")])
+
+
+def fixture_run(trace):
     class Run:
-        trace, frames, steps = tr, 2, 2
+        frames, steps = 2, 2
         work = {"fragments": 1000, "pixels": 4096}
         peaks = spec.peaks_for("TPU v5 lite")
+        counters = {"setup": {"jit_s": 12.5, "programs": 65},
+                    "window": {"jit_s": 0.0, "programs": 0}}
 
-    value = read(Run)
+    Run.trace = trace
+    return Run
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
+def test_every_metric_reader_loads_and_reads(metric):
+    value = spec.load_reader(metric)(fixture_run(scoped_trace()))
     assert value is not None and value >= 0
 
 
@@ -58,8 +81,54 @@ def test_a_reader_with_nothing_to_read_returns_nothing(metric):
         frames, steps = 2, 2
         work = {"fragments": 0, "pixels": 0}
         peaks = spec.peaks_for("TPU v5 lite")
+        counters = {"setup": {"jit_s": 0.0, "programs": 0},
+                    "window": {"jit_s": 0.0, "programs": 0}}
 
     assert spec.load_reader(metric)(Run) is None
+
+
+MS = 1e-6 / 2                          # ns in the window -> ms per frame
+
+
+@pytest.mark.parametrize("metric,expected", [
+    ("project.ms_per_frame", 100 * MS),
+    ("frag_build.ms_per_frame", 100 * MS),
+    ("raster_glue.ms_per_frame", 100 * MS),
+    ("optim.ms_per_frame", 50 * MS),
+    ("step_unscoped.ms_per_frame", 50 * MS),     # the WSU schedule + copy.5
+    ("track.ms_per_frame", 525 * MS),            # forward kernel included
+    ("map.ms_per_frame", 350 * MS),              # backward kernel included
+    ("setup.jit_s", 12.5),
+])
+def test_scope_and_counter_readers_on_a_fixture_run(metric, expected):
+    assert spec.load_reader(metric)(fixture_run(scoped_trace())) == \
+        pytest.approx(expected, rel=1e-12)
+
+
+BUCKETS = ("project", "frag_build", "raster_glue", "optim", "step_unscoped")
+
+
+def test_the_five_buckets_add_up_to_step_xla():
+    run = fixture_run(scoped_trace())
+    parts = [spec.load_reader(f"{b}.ms_per_frame")(run) for b in BUCKETS]
+    step_xla = spec.load_reader("step_xla.ms_per_frame")(run)
+    assert step_xla == pytest.approx(400 * MS, rel=1e-12)
+    assert sum(parts) == pytest.approx(step_xla, rel=1e-12)
+
+
+def test_scope_readers_need_scope_paths():
+    """A profile whose operations carry no scope path (no program metadata)
+    gives the scope readers nothing to read; the others still read."""
+    import dataclasses
+
+    tr = scoped_trace()
+    tr.devices = [[dataclasses.replace(e, scope="") for e in ops]
+                  for ops in tr.devices]
+    run = fixture_run(tr)
+    for b in BUCKETS + ("track", "map"):
+        assert spec.load_reader(f"{b}.ms_per_frame")(run) is None, b
+    assert spec.load_reader("step_xla.ms_per_frame")(run) == \
+        pytest.approx(400 * MS, rel=1e-12)
 
 
 def _modules(kind):

@@ -45,6 +45,23 @@ def test_traced_rehearsal_reports_per_layer_metrics_only():
     assert "busy_s" in r["device"] and "window_s" in r["device"]
 
 
+def test_traced_rehearsal_hands_counters_and_work_to_the_readers():
+    """``RunData.counters`` holds the build counters at the end of set-up
+    and what the window added (nothing: every program is built in set-up),
+    and ``RunData.work`` every ``DeviceWork`` counter of the window."""
+    from repro.slam.metrics import DeviceWork
+
+    r = bench_run.run(args(SOLO, seed=13, trace=1))
+    assert r["correct"] is True
+    setup, window = r["builds"]["setup"], r["builds"]["window"]
+    assert setup["programs"] > 0 and setup["jit_s"] > 0
+    assert window["programs"] == 0 and window["jit_s"] == 0
+    assert r["metrics"]["setup.jit_s"]["value"] == setup["jit_s"]
+    work = r["window"]["work"]
+    assert set(work) == set(DeviceWork._fields)
+    assert work["fragments"] > 0 and work["pixels"] > 0
+
+
 def _stale_state(system):
     """Fault: the step returns its state unchanged."""
     real = system.step_fn

@@ -3,6 +3,8 @@ covers whole keyframe periods, and the check gets the run's first
 ``checked_frames`` frame-steps in order, from warm-up, window and the
 steps carried on after the window closed."""
 
+from types import SimpleNamespace
+
 import pytest
 
 from bench import harness
@@ -19,8 +21,9 @@ class Stub:
 
     def fetch(self):
         kf = self.n % 4 == 0
-        return [{"frame": self.n, "pose": [[0.0]], "fragments": 10,
-                 "pixels": 256, "is_kf": kf if i != self.offbeat else not kf}
+        return [{"frame": self.n, "pose": [[0.0]],
+                 "work": {"fragments": 10, "pixels": 256},
+                 "is_kf": kf if i != self.offbeat else not kf}
                 for i in range(self.streams)]
 
 
@@ -53,3 +56,38 @@ def test_a_stream_off_its_keyframe_beat_fails_the_open_frames():
     harness.warm_up(s, 4)
     w = harness.measure(s, 10.0, 16, annotate=False)
     assert w.periods == 0 and w.steps == 1 and w.failed == w.frames == 2
+
+
+class DeviceWorkStub(Stub):
+    """The program's step result: each frame-step's ``DeviceWork`` holds
+    its field's position plus the step number, so every counter sums to
+    something of its own."""
+
+    def fetch(self):
+        import numpy as np
+
+        from repro.slam.metrics import DeviceWork
+
+        kf = self.n % 4 == 0
+        out = SimpleNamespace(
+            pose=np.eye(4, dtype=np.float32), is_kf=np.bool_(kf),
+            track_losses=np.zeros(3, np.float32),
+            map_losses=np.zeros(4, np.float32), psnr=np.float32(20.0),
+            work=DeviceWork(*(np.int32(i + self.n)
+                              for i in range(len(DeviceWork._fields)))))
+        return [harness.record(harness.fetch(out))]
+
+
+def test_every_device_work_counter_is_summed_over_the_window():
+    from repro.slam.metrics import DeviceWork
+
+    s = DeviceWorkStub()
+    harness.warm_up(s, 4)                            # steps 1-4
+    w = harness.measure(s, 0.0, 16, annotate=False)  # steps 5-8
+    assert w.steps == 4
+    assert set(w.work) == set(DeviceWork._fields)
+    for i, name in enumerate(DeviceWork._fields):
+        assert w.work[name] == sum(i + n for n in range(5, 9)), name
+    # The two counters the rooflines read keep their names and meaning.
+    assert w.work["fragments"] == sum(range(5, 9))       # field 0
+    assert w.work["pixels"] == 4 + sum(range(5, 9))      # field 1
